@@ -12,8 +12,9 @@ import sys
 ARCH = sys.argv[1] if len(sys.argv) > 1 else "gemma3-1b"
 
 # inherit the caller's environment (jax flags, tmpdirs, PATH) and only
-# overlay what the child actually needs
-ENV = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
+# overlay what the child actually needs; the children run one at a time, so
+# each can hold the accelerator in turn
+ENV = {**os.environ, "PYTHONPATH": "src"}
 
 subprocess.run(
     [sys.executable, "-m", "repro.launch.serve", "--model", "mlp",

@@ -1,3 +1,21 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's sparse hot spots, their jnp oracles
+(``ref.py``) and jitted wrappers (``ops.py``).
+
+Every ``pallas_call`` takes its mode from ``_interpret()``: compiled
+(Mosaic) on a TPU, the Pallas interpreter on the CPU so tests run the
+same kernel bodies there, and an error anywhere else.
+"""
+import jax
+
+
+def _interpret() -> bool:
+    """Pallas interpret mode for the default backend: False on ``tpu``,
+    True on ``cpu``; no other platform runs these kernels."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on tpu or interpreted on cpu; the "
+        f"default backend is {platform!r}")

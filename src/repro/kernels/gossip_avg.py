@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import _interpret
+
 BLOCK_N = 1024  # lanes per grid step (multiple of 128)
 
 
@@ -33,9 +35,9 @@ def _gossip_kernel(w_ref, m_ref, own_ref, out_ref):
     out_ref[...] = ((num / den) * own).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
+@functools.partial(jax.jit, static_argnames=("block_n",))
 def gossip_avg_flat(w_stack: jax.Array, m_stack: jax.Array, own_mask: jax.Array,
-                    interpret: bool = True, block_n: int = BLOCK_N) -> jax.Array:
+                    block_n: int = BLOCK_N) -> jax.Array:
     """w_stack, m_stack: (J, N); own_mask: (N,).  Returns (N,)."""
     j, n = w_stack.shape
     pad = (-n) % block_n
@@ -55,6 +57,6 @@ def gossip_avg_flat(w_stack: jax.Array, m_stack: jax.Array, own_mask: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), w_stack.dtype),
-        interpret=interpret,
+        interpret=_interpret(),
     )(w_stack, m_stack, own_mask[None, :])
     return out[0, :n]

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import _interpret
+
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 128
 
 
@@ -60,10 +62,10 @@ def block_mask_from_mask(mask: jax.Array, bk: int, bn: int) -> jax.Array:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+    jax.jit, static_argnames=("bm", "bn", "bk"))
 def masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
-                  bm: int = DEFAULT_BM, bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-                  interpret: bool = True) -> jax.Array:
+                  bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
+                  bk: int = DEFAULT_BK) -> jax.Array:
     """x: (M, K); w, mask: (K, N).  Shapes must tile evenly (wrapper in
     ops.py pads arbitrary shapes)."""
     m_dim, k_dim = x.shape
@@ -88,7 +90,7 @@ def masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m_dim, n_dim), x.dtype),
-        interpret=interpret,
+        interpret=_interpret(),
     )(bmask, x, w, mask)
 
 
@@ -127,11 +129,10 @@ def batched_block_mask(mask: jax.Array, bk: int, bn: int) -> jax.Array:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+    jax.jit, static_argnames=("bm", "bn", "bk"))
 def batched_masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
                           bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                          bk: int = DEFAULT_BK,
-                          interpret: bool = True) -> jax.Array:
+                          bk: int = DEFAULT_BK) -> jax.Array:
     """y[u] = x[u] @ (w[u] ⊙ m[u]) for every user u, one device launch.
 
     x: (U, M, K); w, mask: (U, K, N).  Shapes must tile evenly (the wrapper
@@ -163,5 +164,5 @@ def batched_masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((u_dim, m_dim, n_dim), x.dtype),
-        interpret=interpret,
+        interpret=_interpret(),
     )(bmask, x, w, mask)

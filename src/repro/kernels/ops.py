@@ -2,13 +2,12 @@
 
 These handle arbitrary shapes (padding/reshaping to tile-aligned layouts),
 threshold computation for prune/regrow, and pytree-level convenience APIs.
-``interpret`` defaults to True because this container is CPU-only; on real
-TPU hardware pass interpret=False (the kernels are written for the TPU
-lowering: MXU-aligned tiles, scalar prefetch, VMEM scratch).
+The kernels are written for the TPU lowering (MXU-aligned tiles, scalar
+prefetch, VMEM scratch); the backend picks compiled or interpret mode
+(``repro.kernels._interpret``).
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -30,18 +29,17 @@ PyTree = Any
 
 
 def gossip_avg(w_list: list[jax.Array], m_list: list[jax.Array],
-               own_mask: jax.Array, interpret: bool = True) -> jax.Array:
+               own_mask: jax.Array) -> jax.Array:
     """Intersection-weighted average of J same-shape tensors (self first)."""
     shape = own_mask.shape
     w_stack = jnp.stack([w.reshape(-1) for w in w_list])
     m_stack = jnp.stack([m.reshape(-1) for m in m_list])
-    out = gossip_avg_flat(w_stack, m_stack, own_mask.reshape(-1),
-                          interpret=interpret)
+    out = gossip_avg_flat(w_stack, m_stack, own_mask.reshape(-1))
     return out.reshape(shape)
 
 
 def gossip_avg_tree(params_list: list[PyTree], masks_list: list[PyTree],
-                    own_mask: PyTree, interpret: bool = True) -> PyTree:
+                    own_mask: PyTree) -> PyTree:
     """Pytree-level gossip (self must be params_list[0]/masks_list[0])."""
     flat = [jax.tree.leaves(p) for p in params_list]
     flat_m = [jax.tree.leaves(m) for m in masks_list]
@@ -49,7 +47,7 @@ def gossip_avg_tree(params_list: list[PyTree], masks_list: list[PyTree],
     out = []
     for i, own in enumerate(own_leaves):
         out.append(gossip_avg([f[i] for f in flat], [f[i] for f in flat_m],
-                              own, interpret=interpret))
+                              own))
     return jax.tree.unflatten(treedef, out)
 
 
@@ -59,8 +57,7 @@ def gossip_avg_tree(params_list: list[PyTree], masks_list: list[PyTree],
 
 
 def masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
-                  bm: int = 128, bn: int = 128, bk: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  bm: int = 128, bn: int = 128, bk: int = 128) -> jax.Array:
     """y = x @ (w ⊙ mask) with zero-block skipping; pads to tile multiples."""
     m_dim, k_dim = x.shape
     k2, n_dim = w.shape
@@ -69,14 +66,13 @@ def masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
     xp = jnp.pad(x, ((0, pm), (0, pk)))
     wp = jnp.pad(w, ((0, pk), (0, pn)))
     mp = jnp.pad(mask, ((0, pk), (0, pn)))
-    y = _masked_matmul_tiled(xp, wp, mp, bm=bm, bn=bn, bk=bk,
-                             interpret=interpret)
+    y = _masked_matmul_tiled(xp, wp, mp, bm=bm, bn=bn, bk=bk)
     return y[:m_dim, :n_dim]
 
 
 def batched_masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
-                          bm: int = 128, bn: int = 128, bk: int = 128,
-                          interpret: bool = True) -> jax.Array:
+                          bm: int = 128, bn: int = 128, bk: int = 128
+                          ) -> jax.Array:
     """y[u] = x[u] @ (w[u] ⊙ mask[u]) in one launch — the multi-tenant
     serving matmul (repro.serve).  Pads M/K/N to tile multiples; the user
     dim U is a grid dimension, never padded."""
@@ -87,8 +83,7 @@ def batched_masked_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
     xp = jnp.pad(x, ((0, 0), (0, pm), (0, pk)))
     wp = jnp.pad(w, ((0, 0), (0, pk), (0, pn)))
     mp = jnp.pad(mask, ((0, 0), (0, pk), (0, pn)))
-    y = _batched_masked_matmul_tiled(xp, wp, mp, bm=bm, bn=bn, bk=bk,
-                                     interpret=interpret)
+    y = _batched_masked_matmul_tiled(xp, wp, mp, bm=bm, bn=bn, bk=bk)
     return y[:, :m_dim, :n_dim]
 
 
@@ -110,7 +105,7 @@ def block_occupancy(mask: jax.Array, bk: int = 128, bn: int = 128) -> float:
 
 
 def prune_regrow(w: jax.Array, g: jax.Array, m: jax.Array,
-                 prune_rate: float, interpret: bool = True):
+                 prune_rate: float):
     """Threshold-based Alg. 2 apply for one layer.
 
     Thresholds are derived from the exact counts (kth order statistics), so
@@ -132,6 +127,5 @@ def prune_regrow(w: jax.Array, g: jax.Array, m: jax.Array,
     sorted_grow = jnp.sort(grow_scores)[::-1]
     g_thresh = sorted_grow[jnp.maximum(n_prune - 1, 0)]
 
-    new_m, new_w = prune_regrow_flat(wf, gf, mf, w_thresh, g_thresh,
-                                     interpret=interpret)
+    new_m, new_w = prune_regrow_flat(wf, gf, mf, w_thresh, g_thresh)
     return new_m.reshape(m.shape).astype(m.dtype), new_w.reshape(w.shape)

@@ -21,9 +21,14 @@ independent and the grid is embarrassingly parallel.
 
 Layout: 2D ``(1, N)`` arrays (TPU wants >= 2D); ``block_n`` coordinates per
 grid step (multiple of 128 lanes and of the 32-bit word size).  ``values``
-is padded by one block so a window load never overruns.  ``interpret``
-defaults to True (this container is CPU-only); the jnp oracle is
-``repro.kernels.ref.packed_accum_ref``.
+is padded by one block so a window load never overruns.  The jnp oracle
+is ``repro.kernels.ref.packed_accum_ref``.
+
+Neither kernel compiles for TPU yet: Mosaic refuses the ``(1, block_n // 32)``
+word block and, in the rows form, the ``(1, block_n)`` row block of a
+``(K, N)`` array (the last two block dims must be divisible by (8, 128) or
+equal the array's).  They run in interpret mode on CPU and are off every
+default path (``backend="ref"``).
 """
 from __future__ import annotations
 
@@ -32,6 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import _interpret
 
 BLOCK_N = 1024  # coords per grid step: 8 sublane rows of 128 lanes, 32 words
 
@@ -59,11 +66,10 @@ def _packed_accum_kernel(num_ref, den_ref, words_ref, values_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("interpret", "block_n"))
+                   static_argnames=("block_n",))
 def packed_accum_flat(num: jax.Array, den: jax.Array, words: jax.Array,
                       values: jax.Array, offsets: jax.Array,
-                      alpha: jax.Array, interpret: bool = True,
-                      block_n: int = BLOCK_N):
+                      alpha: jax.Array, block_n: int = BLOCK_N):
     """num, den: (N,) f32 with N a multiple of ``block_n``; words:
     (N // 32,) uint32; values: (nnz + block_n,) zero-padded; offsets:
     (N // block_n,) int32 exclusive prefix of per-block popcounts; alpha:
@@ -94,18 +100,17 @@ def packed_accum_flat(num: jax.Array, den: jax.Array, words: jax.Array,
             jax.ShapeDtypeStruct((1, n), den.dtype),
         ],
         input_output_aliases={0: 0, 1: 1},
-        interpret=interpret,
+        interpret=_interpret(),
     )(num[None, :], den[None, :], words[None, :], values[None, :],
       offsets[None, :], jnp.asarray(alpha, jnp.float32).reshape(1, 1))
     return num2[0], den2[0]
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("interpret", "block_n"))
+                   static_argnames=("block_n",))
 def packed_accum_rows(num: jax.Array, den: jax.Array, words: jax.Array,
                       values: jax.Array, offsets: jax.Array,
-                      alpha: jax.Array, interpret: bool = True,
-                      block_n: int = BLOCK_N):
+                      alpha: jax.Array, block_n: int = BLOCK_N):
     """Client-stacked form of ``packed_accum_flat``: fold K packed payloads
     into K accumulator rows in one launch.
 
@@ -148,7 +153,7 @@ def packed_accum_rows(num: jax.Array, den: jax.Array, words: jax.Array,
             jax.ShapeDtypeStruct((k, n), den.dtype),
         ],
         input_output_aliases={0: 0, 1: 1},
-        interpret=interpret,
+        interpret=_interpret(),
     )(num, den, words, values, offsets,
       jnp.asarray(alpha, jnp.float32).reshape(1, 1))
     return num2, den2

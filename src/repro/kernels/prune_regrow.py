@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import _interpret
+
 BLOCK = 2048
 
 
@@ -40,10 +42,10 @@ def _pr_kernel(w_ref, g_ref, m_ref, th_ref, new_m_ref, new_w_ref):
     new_w_ref[...] = (w * keep.astype(jnp.float32)).astype(new_w_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+@functools.partial(jax.jit, static_argnames=("block",))
 def prune_regrow_flat(w: jax.Array, g: jax.Array, m: jax.Array,
                       w_thresh: jax.Array, g_thresh: jax.Array,
-                      interpret: bool = True, block: int = BLOCK):
+                      block: int = BLOCK):
     """All inputs (N,); thresholds scalars.  Returns (new_mask, new_weights)."""
     n = w.shape[0]
     pad = (-n) % block
@@ -70,6 +72,6 @@ def prune_regrow_flat(w: jax.Array, g: jax.Array, m: jax.Array,
             jax.ShapeDtypeStruct((1, n_pad), m.dtype),
             jax.ShapeDtypeStruct((1, n_pad), w.dtype),
         ],
-        interpret=interpret,
+        interpret=_interpret(),
     )(w[None, :], g[None, :], m[None, :], th)
     return new_m[0, :n], new_w[0, :n]

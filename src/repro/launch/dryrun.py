@@ -121,10 +121,7 @@ def run_one(arch_name: str, shape_name: str, multi_pod: bool,
         print("[memory_analysis] unavailable:", e)
 
     # ---- cost ------------------------------------------------------------
-    cost_raw = compiled.cost_analysis()
-    if isinstance(cost_raw, (list, tuple)):  # jax<=0.4.x: list of dicts
-        cost_raw = cost_raw[0] if cost_raw else {}
-    cost = {k: float(v) for k, v in cost_raw.items()
+    cost = {k: float(v) for k, v in compiled.cost_analysis().items()
             if isinstance(v, (int, float)) and k in
             ("flops", "bytes accessed", "optimal_seconds")}
     print("[cost_analysis]", {k: f"{v:.3e}" for k, v in cost.items()})

@@ -54,7 +54,9 @@ def build_store(args, model):
     return store
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the server's flags (``argv`` defaults to
+    ``sys.argv[1:]``)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--users", type=int, default=64)
     ap.add_argument("--cache-size", type=int, default=16, dest="cache_size")
@@ -90,10 +92,15 @@ def main() -> None:
                     help="write a run archive (manifest, counters, series, "
                          "trace, health events) to this directory; implies "
                          "tracing.  Render with repro.launch.dash")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.trace_mode is not None and not (args.trace or args.run_dir):
         ap.error("--trace-mode requires --trace or --run-dir")
+    return args
 
+
+def run_serve(args):
+    """Build the store and engine, replay the request stream and write
+    the requested artifacts.  Returns ``(engine, result)``."""
     from repro.serve.batcher import RequestStream
     from repro.serve.engine import ServeEngine
     from repro.sim.report import MetricsStream
@@ -148,6 +155,15 @@ def main() -> None:
             print(f"[health] {ev.severity}: {ev.kind} — {ev.message}")
         print(f"saved run archive {manifest.run_id} to {args.run_dir} "
               f"({len(events)} health events)")
+    return engine, result
+
+
+def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = parse_args(argv)
+    enable_compile_cache()
+    run_serve(args)
 
 
 if __name__ == "__main__":

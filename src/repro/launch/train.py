@@ -25,7 +25,9 @@ import time
 import numpy as np
 
 
-def run_simulate(args) -> dict:
+def run_simulate(args, callbacks=()) -> dict:
+    """Run the ``simulate`` mode; ``callbacks`` (``repro.fl.Callback``)
+    see the engine after every round, beside the ones the flags add."""
     from repro.checkpoint import save_clients
     from repro.data import build_federated_image_task
     from repro.fl import (
@@ -55,7 +57,7 @@ def run_simulate(args) -> dict:
         density=args.density, capacities=capacities, seed=args.seed,
         drop_prob=args.drop_prob, eval_every=args.eval_every)
 
-    callbacks = []
+    callbacks = list(callbacks)
     if args.log_jsonl:
         callbacks.append(JsonlLogger(args.log_jsonl))
     if args.checkpoint:
@@ -305,7 +307,9 @@ def run_lm(args) -> dict:
     return out
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the launcher's flags (``argv`` defaults to
+    ``sys.argv[1:]``), resolving the simulator defaults."""
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="mode", required=True)
 
@@ -438,7 +442,7 @@ def main() -> None:
                     dest="tokens_per_client")
     lm.add_argument("--seed", type=int, default=0)
 
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.mode == "simulate":
         if args.scale and args.sim:
             ap.error("--scale and --sim are mutually exclusive engines")
@@ -485,6 +489,15 @@ def main() -> None:
                             else args.uplink_mode)
         if args.sim and args.bandwidth_skew < 1.0:
             ap.error("--bandwidth-skew must be >= 1 (1 = uniform links)")
+    return args
+
+
+def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = parse_args(argv)
+    enable_compile_cache()
+    if args.mode == "simulate":
         run_simulate(args)
     else:
         run_lm(args)

@@ -77,8 +77,7 @@ class ServeEngine:
 
     def __init__(self, store: ModelStore, model, backend: str = "vmap",
                  max_batch: int = 8, max_wait: float = 0.005,
-                 interpret: bool = True, metrics=None,
-                 metrics_every: int = 8):
+                 metrics=None, metrics_every: int = 8):
         if backend not in model.backends():
             raise ValueError(
                 f"backend {backend!r} not supported by this model "
@@ -88,7 +87,6 @@ class ServeEngine:
         self.backend = backend
         self.max_batch = min(int(max_batch), store.cache_size)
         self.max_wait = float(max_wait)
-        self.interpret = bool(interpret)
         self.metrics = metrics
         self.metrics_every = int(metrics_every)
         # obs layer 2: engine-lifetime latency sketches + throughput series
@@ -122,8 +120,7 @@ class ServeEngine:
             with span("serve.forward", track="serve"):
                 y = self.model.batched_forward(self.store.pool_params,
                                                self.store.pool_masks, x_pool,
-                                               backend=self.backend,
-                                               interpret=self.interpret)
+                                               backend=self.backend)
                 y = np.asarray(jax.block_until_ready(y))
         service_s = time.perf_counter() - t0
         return y[np.asarray(slots)], service_s
@@ -138,8 +135,7 @@ class ServeEngine:
         t0 = time.perf_counter()
         y = self.model.batched_forward(self.store.pool_params,
                                        self.store.pool_masks, x_pool,
-                                       backend=self.backend,
-                                       interpret=self.interpret)
+                                       backend=self.backend)
         jax.block_until_ready(y)
         return time.perf_counter() - t0
 

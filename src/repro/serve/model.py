@@ -82,7 +82,7 @@ class MLPModel:
                 h = _relu(h)
         return h
 
-    def _build(self, backend: str, interpret: bool):
+    def _build(self, backend: str):
         if backend == "vmap":
             def fwd(ps, ms, xs):
                 del ms  # params are already w ⊙ m
@@ -91,9 +91,7 @@ class MLPModel:
         if backend == "ref":
             from repro.kernels.ref import batched_masked_matmul_ref as bmm
         elif backend == "pallas":
-            from repro.kernels.ops import batched_masked_matmul as _pallas_bmm
-            import functools
-            bmm = functools.partial(_pallas_bmm, interpret=interpret)
+            from repro.kernels.ops import batched_masked_matmul as bmm
         else:
             raise ValueError(f"backend must be one of {BACKENDS}, got {backend}")
 
@@ -107,13 +105,11 @@ class MLPModel:
         return jax.jit(fwd)
 
     def batched_forward(self, params_stack: PyTree, masks_stack: PyTree,
-                        xs: jax.Array, backend: str = "vmap",
-                        interpret: bool = True) -> jax.Array:
+                        xs: jax.Array, backend: str = "vmap") -> jax.Array:
         """xs: (U, rows, d_in) -> (U, rows, n_out); one launch per layer."""
-        key = (backend, interpret)
-        if key not in self._jfwd:
-            self._jfwd[key] = self._build(backend, interpret)
-        return self._jfwd[key](params_stack, masks_stack, xs)
+        if backend not in self._jfwd:
+            self._jfwd[backend] = self._build(backend)
+        return self._jfwd[backend](params_stack, masks_stack, xs)
 
     def backends(self) -> tuple[str, ...]:
         return BACKENDS
@@ -142,9 +138,8 @@ class TaskModel:
         return self.task.apply_fn(params, x)
 
     def batched_forward(self, params_stack: PyTree, masks_stack: PyTree,
-                        xs: jax.Array, backend: str = "vmap",
-                        interpret: bool = True) -> jax.Array:
-        del masks_stack, interpret
+                        xs: jax.Array, backend: str = "vmap") -> jax.Array:
+        del masks_stack
         if backend != "vmap":
             raise ValueError(
                 f"TaskModel ({self.task.name}) has no masked-matmul "
@@ -194,9 +189,8 @@ class ArchModel:
         return logits[:, -1, :]
 
     def batched_forward(self, params_stack: PyTree, masks_stack: PyTree,
-                        xs: jax.Array, backend: str = "vmap",
-                        interpret: bool = True) -> jax.Array:
-        del masks_stack, interpret
+                        xs: jax.Array, backend: str = "vmap") -> jax.Array:
+        del masks_stack
         if backend != "vmap":
             raise ValueError(
                 f"ArchModel ({self.cfg.name}) has no masked-matmul "

@@ -20,11 +20,11 @@ clients; the *wire* is strictly O(nnz).
 
 Two backends:
 
-* ``"ref"`` (default) — eager numpy/jnp expansion, the oracle and the fast
-  path on this CPU-only container,
-* ``"pallas"`` — the fused ``repro.kernels.packed_accum`` kernel
-  (interpret-mode here; written for the TPU lowering), accumulating in
-  place block by block.
+* ``"ref"`` (default) — eager numpy/jnp expansion, the oracle and the
+  default path,
+* ``"pallas"`` — the fused ``repro.kernels.packed_accum`` kernel,
+  accumulating in place block by block (interpret mode on CPU; it does
+  not yet compile for TPU, see that module).
 
 ``COUNTERS`` tracks accumulate work (calls / values touched) so tests can
 assert the O(degree · nnz) — not O(K · model) — scaling of the per-client

@@ -1,5 +1,5 @@
 """Per-kernel validation: shape/dtype sweeps vs the pure-jnp oracles in
-ref.py, executed with interpret=True (kernel bodies run on CPU)."""
+ref.py, executed in interpret mode (kernel bodies run on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

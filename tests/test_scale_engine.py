@@ -390,12 +390,13 @@ MESH_PODS = _FakeMesh((2, 2, 2), ("pod", "data", "model"))
 def test_stacked_spec_resolves_on_test_meshes():
     from repro.sharding.rules import stacked_spec
 
-    # K=8 divides both client-axis products
-    assert stacked_spec((8, 3, 3, 2, 4), MESH_2X2)[0] == ("data",)
+    # K=8 divides both client-axis products (a one-axis entry is stored
+    # as the bare axis name)
+    assert stacked_spec((8, 3, 3, 2, 4), MESH_2X2)[0] == "data"
     assert stacked_spec((8, 10), MESH_PODS)[0] == ("pod", "data")
     # K=2 on the pods mesh: ('pod','data') product 4 doesn't divide 2 ->
     # trimmed to ('pod',)
-    assert stacked_spec((2, 10), MESH_PODS)[0] == ("pod",)
+    assert stacked_spec((2, 10), MESH_PODS)[0] == "pod"
     # K=1 stays unsharded
     assert stacked_spec((1, 10), MESH_2X2)[0] is None
     # body dims never shard in the stacked layout
@@ -410,7 +411,7 @@ def test_param_and_batch_specs_resolve_on_test_meshes():
     # a stacked matmul weight: client axes lead, 'model' on the out dim
     spec = param_spec("blocks/attn/wq/w", (8, 4, 128, 128), MESH_2X2,
                       fsdp2d=False)
-    assert spec[0] == ("data",)
+    assert spec[0] == "data"
     assert spec[-1] == "model"
     spec = param_spec("blocks/attn/wq/w", (8, 4, 128, 128), MESH_PODS,
                       fsdp2d=False)
