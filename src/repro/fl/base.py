@@ -57,7 +57,13 @@ class Task:
             n = jnp.sum(live.astype(jnp.float32))
             return jnp.sum(correct) * (jnp.float32(1.0) / n)
 
-        self._acc_stacked = jax.jit(jax.vmap(acc_one))
+        def acc_stacked(params, x, y, live):
+            # names the launch's ops ``eval`` in a device trace, beside the
+            # round program's ``mix``/``local``/``evolve``
+            with jax.named_scope("eval"):
+                return jax.vmap(acc_one)(params, x, y, live)
+
+        self._acc_stacked = jax.jit(acc_stacked)
 
     def value_and_grad(self, params, x, y):
         return self._vg(params, jnp.asarray(x), jnp.asarray(y))

@@ -15,6 +15,14 @@ the hot path — so instrumentation can live permanently in engine loops
 (``benchmarks/engine_vmap.py`` gates the enabled-mode ratio, and
 ``tests/test_obs.py`` smokes the disabled call cost).
 
+A span opened with ``annotate=True`` also enters
+``jax.profiler.TraceAnnotation(name)``, enabled or not, so under a profiler
+session it lands in the profiler's trace, on the thread that opened it and
+on the device trace's clock.  That costs one annotation object per span, so
+only round-granularity spans take the flag (``ScaleEngine``'s ``scale.*``
+phases), never per-link or per-codec-call ones.  jax is imported on the
+first annotated span, never by importing this module.
+
 The module-level ``span`` / ``get_tracer`` operate on a process default
 tracer; ``set_tracer`` swaps it (benchmarks use a private instance so an
 overhead probe never clobbers a run-level ``--trace`` capture).
@@ -102,6 +110,37 @@ class _SpanCM:
     def __exit__(self, *exc):
         t = self._tracer
         t._append(self.name, self.track, self._t0, t.now(), WALL, self.attrs)
+        return False
+
+
+class _AnnotatedSpanCM:
+    """A span that also enters ``jax.profiler.TraceAnnotation(name)``;
+    ``tracer`` is None while tracing is disabled (the annotation alone)."""
+
+    __slots__ = ("_tracer", "name", "track", "attrs", "_t0", "_ann")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, track: str,
+                 attrs: dict):
+        self._tracer = tracer
+        self.name = name
+        self.track = track
+        self.attrs = attrs
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = self._tracer.now()
+        return self
+
+    def __exit__(self, *exc):
+        t = self._tracer
+        if t is not None:
+            t._append(self.name, self.track, self._t0, t.now(), WALL,
+                      self.attrs)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -270,10 +309,13 @@ def set_tracer(tracer: Tracer) -> Tracer:
     return old
 
 
-def span(name: str, track: str = "main", **attrs):
+def span(name: str, track: str = "main", annotate: bool = False, **attrs):
     """Module-level ``with span("phase"):`` against the default tracer —
-    the form the engine hot paths use (near-zero cost when disabled)."""
+    the form the engine hot paths use (near-zero cost when disabled;
+    ``annotate=True`` also writes the span into a profiler trace)."""
     t = _DEFAULT
+    if annotate:
+        return _AnnotatedSpanCM(t if t.enabled else None, name, track, attrs)
     if not t.enabled:
         return _NULL
     return _SpanCM(t, name, track, attrs)

@@ -113,6 +113,10 @@ class ScaleEngine(RoundEngine):
         self.scale_obs = CounterSet("scale.engine")
         self._c_step_calls = self.scale_obs.counter("step_calls")
         self._c_step_compiles = self.scale_obs.counter("step_compiles")
+        # bytes of a round's inputs copied host to device, and blocking
+        # device-to-host reads, counted where each is made
+        self._c_input_bytes = self.scale_obs.counter("input_bytes")
+        self._c_host_syncs = self.scale_obs.counter("host_syncs")
         # cumulative step/compile series on the wall clock (counter-kind:
         # the deltas reconcile against the counters above); not
         # checkpointed — a resumed run restarts its series
@@ -145,15 +149,21 @@ class ScaleEngine(RoundEngine):
 
         grad = jax.grad(loss)
 
+        # the phase scopes name every op of the program in its metadata
+        # (``op_name``, the profiler's ``tf_op``), so a device trace splits
+        # the round into the RoundEngine's phases; they change no op
         def round_step(state, mix, bx, by, live, ev_x, ev_y, lr, counts):
-            state = adapter.stacked_mix(state, mix)
-            params = stacked_local_phase(
-                apply_fn, opt, state["params"], adapter.stacked_masks(state),
-                bx, by, live, lr)
+            with jax.named_scope("mix"):
+                state = adapter.stacked_mix(state, mix)
+            with jax.named_scope("local"):
+                params = stacked_local_phase(
+                    apply_fn, opt, state["params"],
+                    adapter.stacked_masks(state), bx, by, live, lr)
             state = {**state, "params": params}
             if evolves:
-                grads = jax.vmap(grad)(params, ev_x, ev_y)
-                state = adapter.stacked_evolve(state, grads, counts)
+                with jax.named_scope("evolve"):
+                    grads = jax.vmap(grad)(params, ev_x, ev_y)
+                    state = adapter.stacked_evolve(state, grads, counts)
             return state
 
         if self.mesh is None:
@@ -246,32 +256,39 @@ class ScaleEngine(RoundEngine):
     # the round
     # ------------------------------------------------------------------
     def _run_one_round(self, t: int) -> RoundMetrics:
+        # the round's host phases are spans that also enter the profiler's
+        # trace (``annotate``), so a device trace can put each idle gap down
+        # to the phase the host was in; only ``scale.comm`` (its first mask
+        # read waits for the step to finish) and ``scale.eval`` block
         cfg = self.cfg
         t0 = time.perf_counter()
-        ctx = self._make_ctx(t)
-        self._pre_round(ctx)
-
-        bx, by, live = self._batch_schedule(ctx)
-        if self.adapter.evolves:
-            ev_x, ev_y = self._evolve_batches(ctx)
-        else:
-            ev_x = ev_y = None
-        mix = jnp.asarray(self.adapter.mix_matrix(ctx))
-        counts = self.adapter.evolve_counts(ctx)
-        step = self._step_fn()
-        if self.mesh is not None:
-            # the step's outputs carry the mesh in their types: give the
-            # state that placement up front (a no-op from round 2 on), or
-            # round 2 sees new input types and compiles the step again
-            self.state = jax.device_put(self.state, self._state_sh)
-        # snapshot the compile counter around the step dispatch only —
-        # _stacked_eval below jit-compiles separately and must not pollute
-        # the "the round step compiled" signal
-        n_compiles = jax_compile_count()
-        with span("scale.step", track="engine", round=t) as sp:
-            self.state = step(
-                self.state, mix, bx, by, live, ev_x, ev_y,
-                jnp.float32(ctx.lr), counts)
+        with span("scale.inputs", track="engine", annotate=True, round=t):
+            ctx = self._make_ctx(t)
+            self._pre_round(ctx)
+            bx, by, live = self._batch_schedule(ctx)
+            if self.adapter.evolves:
+                ev_x, ev_y = self._evolve_batches(ctx)
+            else:
+                ev_x = ev_y = None
+            mix = jnp.asarray(self.adapter.mix_matrix(ctx))
+            counts = self.adapter.evolve_counts(ctx)
+            inputs = (mix, bx, by, live, ev_x, ev_y, jnp.float32(ctx.lr),
+                      counts)
+            self._c_input_bytes.inc(
+                sum(x.nbytes for x in jax.tree.leaves(inputs)))
+        with span("scale.dispatch", track="engine", annotate=True,
+                  round=t) as sp:
+            step = self._step_fn()
+            if self.mesh is not None:
+                # the step's outputs carry the mesh in their types: give the
+                # state that placement up front (a no-op from round 2 on),
+                # or round 2 sees new input types and compiles the step again
+                self.state = jax.device_put(self.state, self._state_sh)
+            # snapshot the compile counter around the step dispatch only —
+            # _stacked_eval below jit-compiles separately and must not
+            # pollute the "the round step compiled" signal
+            n_compiles = jax_compile_count()
+            self.state = step(self.state, *inputs)
             delta = jax_compile_count() - n_compiles
             sp.attrs["compiles"] = delta
         self._c_step_calls.inc()
@@ -283,8 +300,10 @@ class ScaleEngine(RoundEngine):
         self.scale_series.series("step_compiles", kind="counter").observe(
             tw, float(self._c_step_compiles.value))
 
-        comm = self.adapter.round_comm(self.state, ctx)
-        flops = self.adapter.round_flops(ctx)
+        with span("scale.comm", track="engine", annotate=True, round=t):
+            comm = self.adapter.round_comm(self.state, ctx,
+                                           syncs=self._c_host_syncs)
+            flops = self.adapter.round_flops(ctx)
         for key in self._comm:
             self._comm[key].append(float(getattr(comm, key)))
         for key in self._flops:
@@ -292,7 +311,8 @@ class ScaleEngine(RoundEngine):
 
         acc_mean = acc_std = None
         if (t + 1) % cfg.eval_every == 0 or t == cfg.rounds - 1:
-            accs = self._stacked_eval()
+            with span("scale.eval", track="engine", annotate=True, round=t):
+                accs = self._stacked_eval()
             acc_mean = float(np.mean(accs))
             acc_std = float(np.std(accs))
             self._acc_history.append(acc_mean)

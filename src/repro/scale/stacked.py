@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.common import softmax_xent
+from repro.obs import Counter
 from repro.optim import SGDConfig, masked_sgd_step, sgd_step
 from repro.sparse.packed import (
     PackedSparse,
@@ -535,12 +536,16 @@ def fold_stacked(num: PyTree, den: PyTree, packed: PyTree, alpha: float = 1.0,
     return new_num, new_den
 
 
-def stacked_nnz_per_client(stacked_masks: PyTree) -> list[int]:
-    """Per-client nnz of a stacked mask tree (the comm-accounting input)."""
+def stacked_nnz_per_client(stacked_masks: PyTree,
+                           syncs: Optional[Counter] = None) -> list[int]:
+    """Per-client nnz of a stacked mask tree (the comm-accounting input).
+    Each leaf's count is one blocking device read, counted in ``syncs``."""
     total = None
     for leaf in jax.tree.leaves(stacked_masks):
         kdim = leaf.shape[0]
         counts = np.asarray(
             jnp.sum(jnp.reshape(leaf != 0, (kdim, -1)), axis=1))
+        if syncs is not None:
+            syncs.inc()
         total = counts if total is None else total + counts
     return [int(c) for c in total]

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core.accounting import decentralized_comm
 from repro.fl.engine import RoundCtx, StrategyBase
+from repro.obs import Counter
 from repro.scale.stacked import (
     evolve_counts_for,
     masked_gossip_stacked,
@@ -143,7 +144,10 @@ class StackedStrategyBase:
         same models as ``eval_params``, without the host-side unstack."""
         return state["params"]
 
-    def round_comm(self, state: dict, ctx: RoundCtx):
+    def round_comm(self, state: dict, ctx: RoundCtx,
+                   syncs: Optional[Counter] = None):
+        """The round's ``CommReport``; ``syncs`` counts the blocking device
+        reads it makes."""
         raise NotImplementedError
 
     def round_flops(self, ctx: RoundCtx):
@@ -195,8 +199,9 @@ class StackedDisPFL(StackedStrategyBase):
             budgets = base.budgets[0]
         return evolve_counts_for(budgets, ctx.prune_rate)
 
-    def round_comm(self, state: dict, ctx: RoundCtx):
-        nnz = stacked_nnz_per_client(state["masks"])
+    def round_comm(self, state: dict, ctx: RoundCtx,
+                   syncs: Optional[Counter] = None):
+        nnz = stacked_nnz_per_client(state["masks"], syncs=syncs)
         return decentralized_comm(ctx.adjacency, nnz, self.base.n_coords)
 
 
@@ -228,7 +233,8 @@ class StackedDPSGD(StackedStrategyBase):
                 "params": plain_mix_stacked(state["params"], mix,
                                             reduction=self.reduction)}
 
-    def round_comm(self, state: dict, ctx: RoundCtx):
+    def round_comm(self, state: dict, ctx: RoundCtx,
+                   syncs: Optional[Counter] = None):
         n = len(self.base.clients)
         return decentralized_comm(ctx.adjacency,
                                   [self.base.n_coords] * n,
