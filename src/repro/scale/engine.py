@@ -60,6 +60,7 @@ from repro.obs import (
 )
 from repro.optim import SGDConfig
 from repro.scale.stacked import (
+    evolve_topk_work,
     pack_stacked,
     split_stacked,
     stacked_local_phase,
@@ -117,6 +118,10 @@ class ScaleEngine(RoundEngine):
         # device-to-host reads, counted where each is made
         self._c_input_bytes = self.scale_obs.counter("input_bytes")
         self._c_host_syncs = self.scale_obs.counter("host_syncs")
+        # the evolve's exact top-k work, from shapes on the host: (client,
+        # leaf) selections and the count passes they make over their rows
+        self._c_topk_selects = self.scale_obs.counter("topk_selects")
+        self._c_topk_passes = self.scale_obs.counter("topk_passes")
         # cumulative step/compile series on the wall clock (counter-kind:
         # the deltas reconcile against the counters above); not
         # checkpointed — a resumed run restarts its series
@@ -292,6 +297,9 @@ class ScaleEngine(RoundEngine):
             delta = jax_compile_count() - n_compiles
             sp.attrs["compiles"] = delta
         self._c_step_calls.inc()
+        selects, passes = evolve_topk_work(self.state["params"], counts)
+        self._c_topk_selects.inc(selects)
+        self._c_topk_passes.inc(passes)
         if delta > 0:
             self._c_step_compiles.inc()
         tw = time.perf_counter() - self._series_epoch

@@ -25,11 +25,16 @@ Primitives
                             a *traceable* function, so it can fuse into the
                             single round program.
 ``stacked_evolve_exact``    Alg. 2 prune/regrow batched over clients with
-                            *traced* per-layer (n_keep, n_prune) counts —
-                            exact argsort top-k semantics (bit-identical to
-                            ``core.evolve.evolve_mask_layer``), and no
-                            recompilation when the cosine schedule or an
+                            *traced* per-layer (n_keep, n_prune) counts, and
+                            no recompilation when the cosine schedule or an
                             annealed density changes the counts per round.
+                            Each top-k is a per-row threshold search with no
+                            sort and no scatter: a bitwise search for the
+                            k-th largest score's key, then an index cut
+                            among the keys tied there, ties going to the
+                            lower index as a stable descending argsort
+                            breaks them (bit-identical to
+                            ``core.evolve.evolve_mask_layer``).
 ``stacked_prune_regrow_threshold``
                             the threshold-based variant for giant archs
                             (sampled-sort thresholds, tie drift tolerated)
@@ -68,7 +73,7 @@ from repro.sparse.packed import (
     _unpack_bits,
     n_words,
 )
-from repro.utils.tree import tree_map_with_path
+from repro.utils.tree import tree_leaves_with_path, tree_map_with_path
 
 PyTree = Any
 
@@ -220,16 +225,64 @@ def stacked_local_phase(apply_fn: Callable, opt: SGDConfig, params: PyTree,
 # ---------------------------------------------------------------------------
 
 
+_KEY_BITS = 32
+
+
+def topk_row_passes(n: int) -> int:
+    """Count passes ``_topk_rows`` makes over a row block of width ``n``:
+    the threshold search, the count above the threshold, the tie cut."""
+    return _KEY_BITS + 1 + (n - 1).bit_length()
+
+
+def _order_keys(scores: jax.Array) -> jax.Array:
+    """uint32 keys that order like ``-scores`` sorts them: for scores >= +0.0
+    the float bits order like the value; ``-inf`` ranks below every finite
+    score and NaN below ``-inf``, as ``jnp.argsort`` places them."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    key = jnp.where(scores == -jnp.inf, -1, bits)
+    key = jnp.where(jnp.isnan(scores), -2, key)
+    # flipping the sign bit maps int32 order onto uint32 order
+    return jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
 def _topk_rows(scores: jax.Array, k: jax.Array) -> jax.Array:
-    """Per-row {0,1} selection of the ``k`` largest scores, exact count and
-    argsort tie-breaking identical to ``core.evolve._exact_topk_mask``, but
-    with ``k`` *traced* (rank < k instead of a static scatter slice)."""
-    n = scores.shape[1]
-    order = jnp.argsort(-scores, axis=1)
-    rows = jnp.arange(scores.shape[0])[:, None]
-    ranks = jnp.zeros(scores.shape, jnp.int32).at[rows, order].set(
-        jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), scores.shape))
-    return (ranks < k).astype(jnp.float32)
+    """Per-row {0,1} selection of the ``k`` largest scores (``+0.0`` or more,
+    or ``-inf``), with ``k`` *traced*: the set a stable descending argsort
+    selects (``core.evolve._exact_topk_mask``), ties toward the lower index.
+
+    No sort and no scatter: a bitwise search finds each row's threshold key
+    ``t``, the largest with ``count(key >= t) >= k``; then a bitwise search
+    over the index cuts the keys tied at ``t`` so that exactly ``k`` are
+    selected. Every step is one compare-and-row-sum pass over the block
+    (``topk_row_passes``)."""
+    rows, n = scores.shape
+    key = _order_keys(scores)
+    k = jnp.asarray(k, jnp.int32)
+
+    def count(sel):
+        return jnp.sum(sel, axis=1, dtype=jnp.int32)
+
+    def threshold_bit(i, t):
+        cand = t | (jnp.uint32(1) << (_KEY_BITS - 1 - i).astype(jnp.uint32))
+        return jnp.where(count(key >= cand[:, None]) >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, _KEY_BITS, threshold_bit,
+                          jnp.zeros((rows,), jnp.uint32))[:, None]
+    above = key > t
+    tied = key == t
+    need = k - count(above)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+    cut_bits = (n - 1).bit_length()
+
+    # the largest c with count(tied & idx < c) < need: the need-th tied key
+    # sits at index c (k == 0 leaves t at the all-ones key, which no score
+    # maps to, so nothing ties)
+    def cut_bit(i, c):
+        cand = c | (1 << (cut_bits - 1 - i))
+        return jnp.where(count(tied & (idx < cand[:, None])) < need, cand, c)
+
+    c = jax.lax.fori_loop(0, cut_bits, cut_bit, jnp.zeros((rows,), jnp.int32))
+    return (above | (tied & (idx <= c[:, None]))).astype(jnp.float32)
 
 
 def stacked_evolve_exact(params: PyTree, masks: PyTree, grads: PyTree,
@@ -267,6 +320,19 @@ def stacked_evolve_exact(params: PyTree, masks: PyTree, grads: PyTree,
     new_masks = jax.tree.map(lambda t: t[0], paired, is_leaf=is_pair)
     new_params = jax.tree.map(lambda t: t[1], paired, is_leaf=is_pair)
     return new_masks, new_params
+
+
+def evolve_topk_work(params: PyTree, counts: dict) -> tuple[int, int]:
+    """Host-side (selections, count passes) of one ``stacked_evolve_exact``
+    call: a keep and a grow selection per client and counted leaf, each
+    making ``topk_row_passes`` passes over its row. Shapes only, no read."""
+    selects = passes = 0
+    for path, w in tree_leaves_with_path(params):
+        if path in counts:
+            kdim = w.shape[0]
+            selects += 2 * kdim
+            passes += 2 * kdim * topk_row_passes(w.size // kdim)
+    return selects, passes
 
 
 def evolve_counts_for(budgets: dict[str, int], prune_rate: float) -> dict:

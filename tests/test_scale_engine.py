@@ -2,6 +2,7 @@
 parity, stacked packed payload round-trips, sharding spec resolution,
 checkpoint interop, and the sharded subprocess smoke."""
 import os
+import re
 import subprocess
 import sys
 
@@ -10,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.evolve import evolve_masks, layer_nnz_budgets
+from repro.core.evolve import _exact_topk_mask, evolve_masks, layer_nnz_budgets
 from repro.core.gossip import gossip_average_one
 from repro.core.masks import erk_densities_for_params
 from repro.core.topology import make_adjacency
@@ -36,9 +37,14 @@ from repro.scale import (
     stacked_nnz_per_client,
     unpack_stacked,
 )
-from repro.scale.stacked import evolve_counts_for
+from repro.scale.stacked import _topk_rows, evolve_counts_for, topk_row_passes
 from repro.sparse import encoded_nbytes, pack_tree
-from repro.utils.tree import tree_index, tree_stack, tree_unstack
+from repro.utils.tree import (
+    tree_index,
+    tree_leaves_with_path,
+    tree_stack,
+    tree_unstack,
+)
 
 pytestmark = pytest.mark.tier1
 
@@ -304,16 +310,88 @@ def test_plain_mix_stacked_matches_metropolis_reference():
                                        atol=1e-6, rtol=0)
 
 
-def test_stacked_evolve_exact_matches_core_evolve():
+def _topk_rows_by_argsort(scores, k):
+    """The argsort + rank-scatter form ``_topk_rows`` replaced: each row's
+    ranks under a stable descending argsort, selected where rank < k."""
+    n = scores.shape[1]
+    order = jnp.argsort(-scores, axis=1)
+    rows = jnp.arange(scores.shape[0])[:, None]
+    ranks = jnp.zeros(scores.shape, jnp.int32).at[rows, order].set(
+        jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), scores.shape))
+    return (ranks < k).astype(jnp.float32)
+
+
+def _topk_block(n, bf16):
+    rng = np.random.default_rng(n)
+    s = np.stack([
+        np.round(np.abs(rng.normal(size=n)) * 2) / 2,   # heavy ties
+        np.full(n, 0.75),                               # all equal
+        np.full(n, -np.inf),                            # all excluded
+        np.zeros(n),                                    # all +0.0
+        np.where(rng.random(n) < 0.4, -np.inf,          # some excluded
+                 np.abs(rng.normal(size=n))),
+        np.where(rng.random(n) < 0.2, np.nan,           # NaN ranks last
+                 np.round(np.abs(rng.normal(size=n)))),
+        np.abs(rng.normal(size=n)),
+    ]).astype(np.float32)
+    if bf16:
+        s = np.asarray(jnp.asarray(s).astype(jnp.bfloat16).astype(jnp.float32))
+    return jnp.asarray(s)
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("n", [1, 31, 33, 1025])
+def test_topk_rows_matches_argsort_rank_scatter(n, bf16):
+    """The threshold search selects exactly what the stable argsort ranks
+    select, ties and excluded entries included, for every k under one
+    trace (k is a traced scalar)."""
+    scores = _topk_block(n, bf16)
+    traces = []
+
+    @jax.jit
+    def select(s, k):
+        traces.append(1)
+        return _topk_rows(s, k)
+
+    finite = np.isfinite(np.asarray(scores)).sum(axis=1).tolist()
+    for k in sorted({0, 1, n // 2, n, *finite}):
+        got = np.asarray(select(scores, jnp.int32(k)))
+        want = np.asarray(_topk_rows_by_argsort(scores, k))
+        np.testing.assert_array_equal(got, want, err_msg=f"k={k}")
+        assert (got.sum(axis=1) == k).all()
+    assert len(traces) == 1
+    assert topk_row_passes(n) == 33 + (n - 1).bit_length()
+
+
+def _leaf(tree, path):
+    return dict(tree_leaves_with_path(tree))[path]
+
+
+def _cut_in_tie(scores, k) -> bool:
+    """The k-th and (k+1)-th largest scores are equal and finite."""
+    s = np.sort(np.asarray(scores).ravel())[::-1]
+    return 0 < k < s.size and s[k - 1] == s[k] and np.isfinite(s[k])
+
+
+@pytest.mark.parametrize("grid", [None, 0.5, 0.25],
+                         ids=["random", "grid0.5", "grid0.25"])
+def test_stacked_evolve_exact_matches_core_evolve(grid):
     """Batched prune/regrow with traced counts == the per-client reference
-    (same argsort tie-breaks, exact counts), across several prune rates."""
+    (same argsort tie-breaks, exact counts), across several prune rates.
+    On a coarse grid the weights and grads tie in groups that straddle
+    both the keep and the grow cut."""
     w, m = _random_world(seed=5)
     rng = np.random.default_rng(7)
     g = jax.tree.map(
         lambda x: jnp.asarray(rng.normal(size=x.shape).astype(np.float32)), w)
+    if grid is not None:
+        w, g = (jax.tree.map(lambda x: jnp.round(x / grid) * grid, t)
+                for t in (w, g))
+        w = jax.tree.map(lambda a, b: a * b, w, m)
     k = 6
     dens = erk_densities_for_params(tree_index(w, 0), 0.5)
     budgets = layer_nnz_budgets(tree_index(w, 0), dens)
+    straddled = {"keep": False, "grow": False}
     for rate in (0.0, 0.3, 0.77, 1.0):
         ref_m, ref_w = [], []
         for i in range(k):
@@ -322,11 +400,34 @@ def test_stacked_evolve_exact_matches_core_evolve():
             ref_m.append(nm)
             ref_w.append(nw)
         counts = evolve_counts_for(budgets, rate)
+        for path, (n_keep, n_prune) in counts.items():
+            wl, ml, gl = (_leaf(t, path) for t in (w, m, g))
+            for i in range(k):
+                keep = jnp.where(ml[i] > 0, jnp.abs(wl[i]), -jnp.inf)
+                half = _exact_topk_mask(keep, int(n_keep))
+                grow = jnp.where(half > 0, -jnp.inf,
+                                 jnp.abs(gl[i]).reshape(-1))
+                straddled["keep"] |= _cut_in_tie(keep, int(n_keep))
+                straddled["grow"] |= _cut_in_tie(grow, int(n_prune))
         got_m, got_w = jax.jit(
             lambda p, q, r, c: stacked_evolve_exact(p, q, r, c))(
                 w, m, g, counts)
         assert _trees_equal(got_m, tree_stack(ref_m)), rate
         assert _trees_equal(got_w, tree_stack(ref_w)), rate
+    if grid is not None:
+        assert all(straddled.values()), straddled
+
+
+def test_stacked_evolve_exact_lowers_without_sort_or_scatter():
+    """The exact top-k is a threshold search: with traced counts, neither
+    the lowered nor the compiled program holds a sort or a scatter."""
+    w, m = _random_world(seed=3)
+    dens = erk_densities_for_params(tree_index(w, 0), 0.5)
+    counts = evolve_counts_for(
+        layer_nnz_budgets(tree_index(w, 0), dens), 0.3)
+    lowered = jax.jit(stacked_evolve_exact).lower(w, m, w, counts)
+    assert not re.search(r"stablehlo\.(sort|scatter)\b", lowered.as_text())
+    assert not re.search(r"\s(sort|scatter)\(", lowered.compile().as_text())
 
 
 # ---------------------------------------------------------------------------

@@ -144,6 +144,38 @@ def test_input_bytes_and_host_syncs_count_the_round(setup, monkeypatch):
     assert seen[0] >= bx.size * 4
 
 
+def test_topk_counters_count_the_evolve_selections(setup):
+    from repro.scale.stacked import topk_row_passes
+    from repro.utils.tree import tree_leaves_with_path
+
+    eng = _engine(setup)
+    kdim = len(eng.clients)
+    counts = eng.adapter.evolve_counts(eng._make_ctx(0))
+    sizes = [w.size // kdim
+             for path, w in tree_leaves_with_path(eng.state["params"])
+             if path in counts]
+    assert len(sizes) == len(counts) > 0
+    n_leaves = len(jax.tree.leaves(eng.state["masks"]))
+    rounds = eng.rounds()
+    for r in range(1, 3):
+        next(rounds)
+        snap = eng.scale_obs.snapshot()
+        # a keep and a grow selection per client and counted leaf
+        assert snap["topk_selects"] == r * 2 * kdim * len(counts)
+        assert snap["topk_passes"] == r * 2 * kdim * sum(
+            topk_row_passes(n) for n in sizes)
+        assert snap["host_syncs"] == r * n_leaves
+
+
+def test_topk_counters_stay_zero_without_an_evolve(setup):
+    task, clients, cfg = setup
+    eng = ScaleEngine(make_strategy("dpsgd"), task, clients, cfg)
+    next(eng.rounds())
+    snap = eng.scale_obs.snapshot()
+    assert snap["step_calls"] == 1
+    assert snap["topk_selects"] == snap["topk_passes"] == 0
+
+
 def test_disabled_tracer_keeps_the_null_span_and_annotation_still_enters():
     from repro.obs import get_tracer, span
 
