@@ -33,8 +33,12 @@ class ClientData:
             sel = order[i: i + batch_size]
             yield self.train_x[sel], self.train_y[sel]
 
+    def sample_indices(self, rng: np.random.Generator, batch_size: int):
+        """The rows ``sample_batch`` draws: uniform, with replacement."""
+        return rng.integers(0, self.n_train, size=min(batch_size, self.n_train))
+
     def sample_batch(self, rng: np.random.Generator, batch_size: int):
-        sel = rng.integers(0, self.n_train, size=min(batch_size, self.n_train))
+        sel = self.sample_indices(rng, batch_size)
         return self.train_x[sel], self.train_y[sel]
 
 

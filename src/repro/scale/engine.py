@@ -30,6 +30,9 @@ homogeneous client densities, all clients sharing one effective batch size
 (ragged step counts are fine — padded and live-masked exactly like the
 vmap fast path), and a strategy with a registered ``StackedStrategy``
 adapter (``dispfl``, ``dispfl_anneal``, ``dpsgd``).
+
+The clients' training images go to the device once, stacked; a round sends
+only its batch indices and labels, and the step gathers its batches there.
 """
 from __future__ import annotations
 
@@ -106,6 +109,8 @@ class ScaleEngine(RoundEngine):
                               weight_decay=cfg.weight_decay)
         self._round_step = None
         self._eval_arrays = None
+        self._img_shape = tuple(self.clients[0].train_x.shape[1:])
+        self._images = None
         # compile-vs-execute observability: the jax.monitoring bridge makes
         # "traced scalars never recompile" an assertable counter — one
         # backend compile on the first step, zero after, whatever the
@@ -153,21 +158,29 @@ class ScaleEngine(RoundEngine):
             return softmax_xent(apply_fn(p, x), y)
 
         grad = jax.grad(loss)
+        img_shape = self._img_shape
+
+        def gather(images, idx):
+            # each client's rows of its own images: (K, n, F) by (K, ...)
+            x = jax.vmap(lambda d, i: d[i])(images, idx)
+            return x.reshape(idx.shape + img_shape)
 
         # the phase scopes name every op of the program in its metadata
         # (``op_name``, the profiler's ``tf_op``), so a device trace splits
         # the round into the RoundEngine's phases; they change no op
-        def round_step(state, mix, bx, by, live, ev_x, ev_y, lr, counts):
+        def round_step(state, images, mix, bi, by, live, ev_i, ev_y, lr,
+                       counts):
             with jax.named_scope("mix"):
                 state = adapter.stacked_mix(state, mix)
             with jax.named_scope("local"):
                 params = stacked_local_phase(
                     apply_fn, opt, state["params"],
-                    adapter.stacked_masks(state), bx, by, live, lr)
+                    adapter.stacked_masks(state), gather(images, bi), by,
+                    live, lr)
             state = {**state, "params": params}
             if evolves:
                 with jax.named_scope("evolve"):
-                    grads = jax.vmap(grad)(params, ev_x, ev_y)
+                    grads = jax.vmap(grad)(params, gather(images, ev_i), ev_y)
                     state = adapter.stacked_evolve(state, grads, counts)
             return state
 
@@ -191,16 +204,17 @@ class ScaleEngine(RoundEngine):
             return jax.lax.with_sharding_constraint(
                 x, NamedSharding(mesh, stacked_spec(tuple(x.shape), mesh)))
 
-        def sharded_step(state, mix, bx, by, live, ev_x, ev_y, lr, counts):
-            return round_step(state, mix, shard_stacked(bx),
-                              shard_stacked(by), shard_stacked(live),
-                              shard_stacked(ev_x), shard_stacked(ev_y),
-                              lr, counts)
+        def sharded_step(state, images, mix, bi, by, live, ev_i, ev_y, lr,
+                         counts):
+            return round_step(state, shard_stacked(images), mix,
+                              shard_stacked(bi), shard_stacked(by),
+                              shard_stacked(live), shard_stacked(ev_i),
+                              shard_stacked(ev_y), lr, counts)
 
         with use_mesh_rules(mesh):
             return jax.jit(
                 sharded_step,
-                in_shardings=(state_sh,) + (None,) * 8,
+                in_shardings=(state_sh,) + (None,) * 9,
                 out_shardings=state_sh,
             )
 
@@ -219,10 +233,36 @@ class ScaleEngine(RoundEngine):
     # ------------------------------------------------------------------
     # host-side per-round inputs (identical draws to the reference engine)
     # ------------------------------------------------------------------
+    def _device_images(self):
+        """Every client's training images as flat rows, (K, n_max, F) zero
+        padded past each client's ``n_train``, copied to the device on the
+        first call and kept: the round then ships indices, not images.
+        Flat rows keep the minor dim wide (h x w x c), whatever c is."""
+        if self._images is None:
+            feat = int(np.prod(self._img_shape))
+            n_max = max(c.n_train for c in self.clients)
+            host = np.zeros((len(self.clients), n_max, feat),
+                            self.clients[0].train_x.dtype)
+            for k, c in enumerate(self.clients):
+                host[k, :c.n_train] = c.train_x.reshape(c.n_train, feat)
+            if self.mesh is None:
+                self._images = jnp.asarray(host)
+            else:
+                from jax.sharding import NamedSharding
+
+                from repro.sharding.rules import stacked_spec
+
+                self._images = jax.device_put(host, NamedSharding(
+                    self.mesh, stacked_spec(host.shape, self.mesh)))
+            self._c_input_bytes.inc(host.nbytes)
+        return self._images
+
     def _batch_schedule(self, ctx: RoundCtx):
         """Stacked padded batch schedule — the same permutations, padding
         and live-masking as ``RoundEngine._vmap_local_phase`` (and therefore
-        the same draws as the per-client reference loop)."""
+        the same draws as the per-client reference loop): each step's rows
+        of ``_device_images``, (K, steps, bs), its labels and its live
+        flags."""
         cfg = self.cfg
         epochs = self.strategy.local_epochs({}, ctx)
         bs = min(cfg.batch_size, min(c.n_train for c in self.clients))
@@ -233,29 +273,23 @@ class ScaleEngine(RoundEngine):
                 [_pad_order(self.clients[k].n_train, bs, rng)
                  for _ in range(epochs)]))
         s_max = max(len(o) // bs for o in orders)
-        xb, yb, live = [], [], []
-        for k, order in enumerate(orders):
-            steps = len(order) // bs
-            c = self.clients[k]
-            padded = np.resize(order, s_max * bs)
-            xb.append(c.train_x[padded].reshape(
-                (s_max, bs) + c.train_x.shape[1:]))
-            yb.append(c.train_y[padded].reshape(s_max, bs))
-            live.append(np.arange(s_max) < steps)
-        return (jnp.asarray(np.stack(xb)), jnp.asarray(np.stack(yb)),
-                jnp.asarray(np.stack(live)))
+        idx = np.stack([np.resize(o, s_max * bs) for o in orders]).astype(
+            np.int32)
+        labels = np.stack([c.train_y[i] for c, i in zip(self.clients, idx)])
+        live = np.arange(s_max) < np.array([[len(o) // bs] for o in orders])
+        return (idx.reshape(-1, s_max, bs), labels.reshape(-1, s_max, bs),
+                live)
 
     def _evolve_batches(self, ctx: RoundCtx):
-        """The mask-search batches, drawn from the *same* per-client rng
-        stream right after the local-phase orders — exactly the draw order
-        of ``Strategy.evolve`` in the reference engine."""
+        """The mask-search batches' rows of ``_device_images`` and their
+        labels, drawn from the *same* per-client rng stream right after the
+        local-phase orders — exactly the draw order of ``Strategy.evolve``
+        in the reference engine."""
         bs = self.cfg.batch_size
-        xs, ys = [], []
-        for k, c in enumerate(self.clients):
-            xbk, ybk = c.sample_batch(ctx.client_rng(k), bs)
-            xs.append(xbk)
-            ys.append(ybk)
-        return jnp.asarray(np.stack(xs)), jnp.asarray(np.stack(ys))
+        idx = np.stack([c.sample_indices(ctx.client_rng(k), bs)
+                        for k, c in enumerate(self.clients)]).astype(np.int32)
+        labels = np.stack([c.train_y[i] for c, i in zip(self.clients, idx)])
+        return idx, labels
 
     # ------------------------------------------------------------------
     # the round
@@ -270,15 +304,17 @@ class ScaleEngine(RoundEngine):
         with span("scale.inputs", track="engine", annotate=True, round=t):
             ctx = self._make_ctx(t)
             self._pre_round(ctx)
-            bx, by, live = self._batch_schedule(ctx)
+            images = self._device_images()
+            bi, by, live = self._batch_schedule(ctx)
             if self.adapter.evolves:
-                ev_x, ev_y = self._evolve_batches(ctx)
+                ev_i, ev_y = self._evolve_batches(ctx)
             else:
-                ev_x = ev_y = None
-            mix = jnp.asarray(self.adapter.mix_matrix(ctx))
+                ev_i = ev_y = None
             counts = self.adapter.evolve_counts(ctx)
-            inputs = (mix, bx, by, live, ev_x, ev_y, jnp.float32(ctx.lr),
-                      counts)
+            # numpy all through, and one copy to the device for the lot
+            inputs = jax.device_put((
+                self.adapter.mix_matrix(ctx), bi, by, live, ev_i, ev_y,
+                np.float32(ctx.lr), counts))
             self._c_input_bytes.inc(
                 sum(x.nbytes for x in jax.tree.leaves(inputs)))
         with span("scale.dispatch", track="engine", annotate=True,
@@ -293,7 +329,7 @@ class ScaleEngine(RoundEngine):
             # _stacked_eval below jit-compiles separately and must not
             # pollute the "the round step compiled" signal
             n_compiles = jax_compile_count()
-            self.state = step(self.state, *inputs)
+            self.state = step(self.state, images, *inputs)
             delta = jax_compile_count() - n_compiles
             sp.attrs["compiles"] = delta
         self._c_step_calls.inc()

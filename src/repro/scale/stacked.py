@@ -338,13 +338,15 @@ def evolve_topk_work(params: PyTree, counts: dict) -> tuple[int, int]:
 def evolve_counts_for(budgets: dict[str, int], prune_rate: float) -> dict:
     """Host-side per-round counts: the exact ``(n_keep, n_prune)`` integers
     the reference derives per layer (``math.ceil`` on the host float, so no
-    f32 rounding drift against ``core.evolve.evolve_mask_layer``)."""
+    f32 rounding drift against ``core.evolve.evolve_mask_layer``), as numpy
+    int32 scalars: they reach the device with the step's other inputs, not
+    one eager conversion each."""
     import math
 
     out = {}
     for path, n_active in budgets.items():
         n_prune = int(math.ceil(prune_rate * n_active))
-        out[path] = (jnp.int32(n_active - n_prune), jnp.int32(n_prune))
+        out[path] = (np.int32(n_active - n_prune), np.int32(n_prune))
     return out
 
 
@@ -602,16 +604,18 @@ def fold_stacked(num: PyTree, den: PyTree, packed: PyTree, alpha: float = 1.0,
     return new_num, new_den
 
 
+@jax.jit
+def _nnz_per_client(stacked_masks: PyTree) -> jax.Array:
+    return sum(jnp.sum(jnp.reshape(m != 0, (m.shape[0], -1)), axis=1)
+               for m in jax.tree.leaves(stacked_masks))
+
+
 def stacked_nnz_per_client(stacked_masks: PyTree,
                            syncs: Optional[Counter] = None) -> list[int]:
-    """Per-client nnz of a stacked mask tree (the comm-accounting input).
-    Each leaf's count is one blocking device read, counted in ``syncs``."""
-    total = None
-    for leaf in jax.tree.leaves(stacked_masks):
-        kdim = leaf.shape[0]
-        counts = np.asarray(
-            jnp.sum(jnp.reshape(leaf != 0, (kdim, -1)), axis=1))
-        if syncs is not None:
-            syncs.inc()
-        total = counts if total is None else total + counts
+    """Per-client nnz of a stacked mask tree (the comm-accounting input):
+    one program over every leaf and one blocking device read, counted in
+    ``syncs``."""
+    total = np.asarray(_nnz_per_client(stacked_masks))
+    if syncs is not None:
+        syncs.inc()
     return [int(c) for c in total]

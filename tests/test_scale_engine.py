@@ -213,6 +213,41 @@ def test_scale_engine_rejects_unsupported_configs(setup):
         ScaleEngine(make_strategy("dispfl"), task, ragged, cfg)
 
 
+def test_round_inputs_are_rows_of_device_resident_images(setup):
+    """The clients' images go to the device once, as flat rows zero padded
+    to the largest client; a round's local and evolve batches are indices
+    into them, drawn as the reference engine draws its host batches."""
+    task, clients, cfg = setup
+    eng = ScaleEngine(make_strategy("dispfl"), task, clients, cfg)
+    images = eng._device_images()
+    assert eng._device_images() is images
+    images = np.asarray(images)
+    feat = int(np.prod(clients[0].train_x.shape[1:]))
+    assert images.shape == (len(clients), max(c.n_train for c in clients),
+                            feat)
+    assert len({c.n_train for c in clients}) > 1      # padding exercised
+    for k, c in enumerate(clients):
+        np.testing.assert_array_equal(images[k, :c.n_train],
+                                      c.train_x.reshape(c.n_train, feat))
+        assert not images[k, c.n_train:].any()
+
+    ctx = eng._make_ctx(1)
+    bi, by, live = (np.asarray(a) for a in eng._batch_schedule(ctx))
+    ev_i, ev_y = (np.asarray(a) for a in eng._evolve_batches(ctx))
+    assert bi.dtype == ev_i.dtype == np.int32
+    ref = eng._make_ctx(1)
+    eng._batch_schedule(ref)             # the local phase's draws come first
+    for k, c in enumerate(clients):
+        assert bi[k].max() < c.n_train
+        np.testing.assert_array_equal(by[k], c.train_y[bi[k]])
+        assert live[k].sum() == cfg.local_epochs * -(-c.n_train
+                                                     // cfg.batch_size)
+        x, y = c.sample_batch(ref.client_rng(k), cfg.batch_size)
+        np.testing.assert_array_equal(images[k][ev_i[k]],
+                                      x.reshape(len(x), feat))
+        np.testing.assert_array_equal(ev_y[k], y)
+
+
 def test_stacked_eval_golden_equal_to_loop(setup):
     """The vmapped personalized eval replacing the per-client host loop is
     bit-equal to it — on round-0 state and on a trained trajectory, with

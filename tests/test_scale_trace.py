@@ -5,6 +5,7 @@ in the ``repro.obs`` tracer, the input-byte and host-sync counters, and
 from __future__ import annotations
 
 import glob
+import math
 import os
 import re
 import subprocess
@@ -50,10 +51,11 @@ def _engine(setup):
 
 def _round_inputs(eng, t=0):
     ctx = eng._make_ctx(t)
-    bx, by, live = eng._batch_schedule(ctx)
-    ev_x, ev_y = eng._evolve_batches(ctx)
-    return (jnp.asarray(eng.adapter.mix_matrix(ctx)), bx, by, live, ev_x,
-            ev_y, jnp.float32(ctx.lr), eng.adapter.evolve_counts(ctx))
+    bi, by, live = eng._batch_schedule(ctx)
+    ev_i, ev_y = eng._evolve_batches(ctx)
+    return (eng._device_images(), jnp.asarray(eng.adapter.mix_matrix(ctx)),
+            bi, by, live, ev_i, ev_y, jnp.float32(ctx.lr),
+            eng.adapter.evolve_counts(ctx))
 
 
 def test_round_program_ops_carry_one_phase_scope(setup):
@@ -127,21 +129,22 @@ def test_input_bytes_and_host_syncs_count_the_round(setup, monkeypatch):
     seen = []
     real = eng._step_fn()
 
-    def recording_step(state, *inputs):
+    def recording_step(state, images, *inputs):
         seen.append(sum(x.nbytes for x in jax.tree.leaves(inputs)))
-        return real(state, *inputs)
+        return real(state, images, *inputs)
 
     monkeypatch.setattr(eng, "_step_fn", lambda: recording_step)
-    n_leaves = len(jax.tree.leaves(eng.state["masks"]))
+    images = eng._device_images().nbytes           # copied once
     rounds = eng.rounds()
     for r in range(1, 3):
         next(rounds)
         snap = eng.scale_obs.snapshot()
-        assert snap["input_bytes"] == sum(seen)
-        assert snap["host_syncs"] == r * n_leaves
-    # the batches dominate: K x steps x batch images, float32
-    bx = _round_inputs(eng)[1]
-    assert seen[0] >= bx.size * 4
+        assert snap["input_bytes"] == images + sum(seen)
+        assert snap["host_syncs"] == r                # every leaf in one read
+    # a round ships the indices of its images, not the images: under a
+    # quarter of the bytes of the float32 batches they pick
+    bi = _round_inputs(eng)[2]
+    assert 0 < seen[1] < bi.size * 4 * math.prod(eng._img_shape) / 4
 
 
 def test_topk_counters_count_the_evolve_selections(setup):
@@ -155,7 +158,6 @@ def test_topk_counters_count_the_evolve_selections(setup):
              for path, w in tree_leaves_with_path(eng.state["params"])
              if path in counts]
     assert len(sizes) == len(counts) > 0
-    n_leaves = len(jax.tree.leaves(eng.state["masks"]))
     rounds = eng.rounds()
     for r in range(1, 3):
         next(rounds)
@@ -164,7 +166,7 @@ def test_topk_counters_count_the_evolve_selections(setup):
         assert snap["topk_selects"] == r * 2 * kdim * len(counts)
         assert snap["topk_passes"] == r * 2 * kdim * sum(
             topk_row_passes(n) for n in sizes)
-        assert snap["host_syncs"] == r * n_leaves
+        assert snap["host_syncs"] == r
 
 
 def test_topk_counters_stay_zero_without_an_evolve(setup):
