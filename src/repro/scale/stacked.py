@@ -29,7 +29,7 @@ Primitives
                             no recompilation when the cosine schedule or an
                             annealed density changes the counts per round.
                             Each top-k is a per-row threshold search with no
-                            sort and no scatter: a bitwise search for the
+                            sort and no scatter: a radix search for the
                             k-th largest score's key, then an index cut
                             among the keys tied there, ties going to the
                             lower index as a stable descending argsort
@@ -226,12 +226,22 @@ def stacked_local_phase(apply_fn: Callable, opt: SGDConfig, params: PyTree,
 
 
 _KEY_BITS = 32
+# Bits of a row's threshold (or tie cut) fixed per pass: a pass tests the
+# 2**_RADIX_BITS - 1 candidates of the next digit in one read of the block.
+# On a TPU v5e, one selection over a (25, 2359296) block took 18.9, 12.6,
+# 11.8, 16.3 and 25.9 ms at 1 to 5 bits: from 4 bits on, a pass is bound
+# by the vector unit's compares (0.92 ms, against 0.47 ms at 3 bits).
+_RADIX_BITS = 3
+
+
+def _digits(bits: int) -> int:
+    return -(-bits // _RADIX_BITS)
 
 
 def topk_row_passes(n: int) -> int:
     """Count passes ``_topk_rows`` makes over a row block of width ``n``:
     the threshold search, the count above the threshold, the tie cut."""
-    return _KEY_BITS + 1 + (n - 1).bit_length()
+    return _digits(_KEY_BITS) + 1 + _digits((n - 1).bit_length())
 
 
 def _order_keys(scores: jax.Array) -> jax.Array:
@@ -245,43 +255,66 @@ def _order_keys(scores: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
 
 
+def _count_pass(select: Callable[[jax.Array], jax.Array],
+                cands: jax.Array) -> jax.Array:
+    """``(rows, C)`` int32: column j counts, per row of the block,
+    ``select(cands[:, j:j+1])``. The C row-sums share one operand, so XLA
+    fuses them into one multi-output reduction over a single read."""
+    return jnp.stack([jnp.sum(select(cands[:, j, None]), axis=1,
+                              dtype=jnp.int32)
+                      for j in range(cands.shape[1])], axis=1)
+
+
+def _radix_search(bits: int, rows: int,
+                  fits: Callable[[jax.Array], jax.Array]) -> jax.Array:
+    """Per row, the largest uint32 ``v < 2**bits`` that ``fits`` (0 if none
+    does), where ``fits`` maps ``(rows, C)`` candidates to bools and, for a
+    row, holds for every value below one that fits. Top digit first, each
+    step tests the candidates ``v + j << shift``, j = 1 .. 2**_RADIX_BITS - 1,
+    and adds the number that fit: they are a prefix of the j."""
+    steps = _digits(bits)
+    js = jnp.arange(1, 1 << _RADIX_BITS, dtype=jnp.uint32)
+    top = jnp.uint32((1 << bits) - 1)
+
+    def step(i, v):
+        shift = (_RADIX_BITS * (steps - 1 - i)).astype(jnp.uint32)
+        cands = v[:, None] + (js << shift)
+        # the top digit is narrower when bits is no multiple of the radix
+        ok = (js <= (top - v[:, None]) >> shift) & fits(cands)
+        return v + (jnp.sum(ok, axis=1, dtype=jnp.uint32) << shift)
+
+    return jax.lax.fori_loop(0, steps, step, jnp.zeros((rows,), jnp.uint32))
+
+
 def _topk_rows(scores: jax.Array, k: jax.Array) -> jax.Array:
     """Per-row {0,1} selection of the ``k`` largest scores (``+0.0`` or more,
     or ``-inf``), with ``k`` *traced*: the set a stable descending argsort
     selects (``core.evolve._exact_topk_mask``), ties toward the lower index.
 
-    No sort and no scatter: a bitwise search finds each row's threshold key
-    ``t``, the largest with ``count(key >= t) >= k``; then a bitwise search
+    No sort and no scatter: a radix search finds each row's threshold key
+    ``t``, the largest with ``count(key >= t) >= k``; then a radix search
     over the index cuts the keys tied at ``t`` so that exactly ``k`` are
-    selected. Every step is one compare-and-row-sum pass over the block
+    selected. Every step is one count pass over the block
     (``topk_row_passes``)."""
     rows, n = scores.shape
     key = _order_keys(scores)
     k = jnp.asarray(k, jnp.int32)
 
-    def count(sel):
-        return jnp.sum(sel, axis=1, dtype=jnp.int32)
-
-    def threshold_bit(i, t):
-        cand = t | (jnp.uint32(1) << (_KEY_BITS - 1 - i).astype(jnp.uint32))
-        return jnp.where(count(key >= cand[:, None]) >= k, cand, t)
-
-    t = jax.lax.fori_loop(0, _KEY_BITS, threshold_bit,
-                          jnp.zeros((rows,), jnp.uint32))[:, None]
+    t = _radix_search(
+        _KEY_BITS, rows,
+        lambda cands: _count_pass(lambda x: key >= x, cands) >= k)[:, None]
     above = key > t
     tied = key == t
-    need = k - count(above)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
-    cut_bits = (n - 1).bit_length()
+    need = k - jnp.sum(above, axis=1, dtype=jnp.int32)
+    idx = jax.lax.broadcasted_iota(jnp.uint32, (rows, n), 1)
 
     # the largest c with count(tied & idx < c) < need: the need-th tied key
     # sits at index c (k == 0 leaves t at the all-ones key, which no score
     # maps to, so nothing ties)
-    def cut_bit(i, c):
-        cand = c | (1 << (cut_bits - 1 - i))
-        return jnp.where(count(tied & (idx < cand[:, None])) < need, cand, c)
-
-    c = jax.lax.fori_loop(0, cut_bits, cut_bit, jnp.zeros((rows,), jnp.int32))
+    c = _radix_search(
+        (n - 1).bit_length(), rows,
+        lambda cands: _count_pass(lambda x: tied & (idx < x), cands)
+        < need[:, None])
     return (above | (tied & (idx <= c[:, None]))).astype(jnp.float32)
 
 

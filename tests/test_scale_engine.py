@@ -37,7 +37,8 @@ from repro.scale import (
     stacked_nnz_per_client,
     unpack_stacked,
 )
-from repro.scale.stacked import _topk_rows, evolve_counts_for, topk_row_passes
+from repro.scale.stacked import (_RADIX_BITS, _count_pass, _topk_rows,
+                                 evolve_counts_for, topk_row_passes)
 from repro.sparse import encoded_nbytes, pack_tree
 from repro.utils.tree import (
     tree_index,
@@ -375,7 +376,7 @@ def _topk_block(n, bf16):
 
 
 @pytest.mark.parametrize("bf16", [False, True], ids=["f32", "bf16"])
-@pytest.mark.parametrize("n", [1, 31, 33, 1025])
+@pytest.mark.parametrize("n", [1, 2, 17, 31, 33, 1025, 4097])
 def test_topk_rows_matches_argsort_rank_scatter(n, bf16):
     """The threshold search selects exactly what the stable argsort ranks
     select, ties and excluded entries included, for every k under one
@@ -395,7 +396,52 @@ def test_topk_rows_matches_argsort_rank_scatter(n, bf16):
         np.testing.assert_array_equal(got, want, err_msg=f"k={k}")
         assert (got.sum(axis=1) == k).all()
     assert len(traces) == 1
-    assert topk_row_passes(n) == 33 + (n - 1).bit_length()
+    digits = lambda bits: -(-bits // _RADIX_BITS)  # noqa: E731
+    assert topk_row_passes(n) == (digits(32) + 1
+                                  + digits((n - 1).bit_length()))
+
+
+def _count_pass_block(rows, n):
+    """A key block with runs of equal keys, the extreme keys 0 and
+    0xFFFFFFFF, and candidates at both ends and inside the runs."""
+    rng = np.random.default_rng(rows * 1000 + n)
+    key = rng.integers(0, 2**32, size=(rows, n), dtype=np.uint64)
+    key[:, ::3] = 0x80000000                    # a run of equal keys
+    key[:, 1::7] = 0xFFFFFFFF
+    key[:, 2::11] = 0
+    key = key.astype(np.uint32)
+    c = (1 << _RADIX_BITS) - 1
+    cands = rng.integers(0, 2**32, size=(rows, c), dtype=np.uint64)
+    cands[:, 0] = 0
+    cands[:, 1 % c] = 0xFFFFFFFF
+    cands[:, 2 % c] = 0x80000000                # inside the run
+    cands[:, 3 % c] = 0x80000001                # just past it
+    return key, cands.astype(np.uint32)
+
+
+@pytest.mark.parametrize("mode", ["threshold", "tie_cut"])
+@pytest.mark.parametrize("rows,n", [(1, 1), (7, 1000), (25, 4097)])
+def test_count_pass_matches_one_candidate_sums(rows, n, mode):
+    """The multi-candidate count pass counts what one plain row-sum per
+    candidate counts: keys ``>= c`` (threshold pass), or keys tied at a
+    threshold with an index below ``c`` (tie-cut pass)."""
+    key, cands = _count_pass_block(rows, n)
+    if mode == "threshold":
+        block = jnp.asarray(key)
+        select = lambda c: block >= c  # noqa: E731
+        want = [np.sum(key >= cands[:, j, None], axis=1)
+                for j in range(cands.shape[1])]
+    else:
+        tied = key == np.uint32(0x80000000)
+        idx = np.broadcast_to(np.arange(n, dtype=np.uint32), key.shape)
+        cands = cands % np.uint32(n + 2)        # cuts inside the row and past it
+        block, jidx = jnp.asarray(tied), jnp.asarray(idx)
+        select = lambda c: block & (jidx < c)  # noqa: E731
+        want = [np.sum(tied & (idx < cands[:, j, None]), axis=1)
+                for j in range(cands.shape[1])]
+    got = jax.jit(lambda c: _count_pass(select, c))(jnp.asarray(cands))
+    assert got.dtype == jnp.int32 and got.shape == cands.shape
+    np.testing.assert_array_equal(np.asarray(got), np.stack(want, axis=1))
 
 
 def _leaf(tree, path):
@@ -463,6 +509,54 @@ def test_stacked_evolve_exact_lowers_without_sort_or_scatter():
     lowered = jax.jit(stacked_evolve_exact).lower(w, m, w, counts)
     assert not re.search(r"stablehlo\.(sort|scatter)\b", lowered.as_text())
     assert not re.search(r"\s(sort|scatter)\(", lowered.compile().as_text())
+
+
+def test_stacked_evolve_exact_on_a_client_mesh_subprocess():
+    """With K sharded over a 4x1 client mesh, the evolve selects the same
+    masks and weights, bit for bit, as on one device, and no key block is
+    gathered across the mesh (the forced device count must precede jax
+    init, hence the subprocess)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    code = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.evolve import layer_nnz_budgets
+from repro.core.masks import erk_densities_for_params
+from repro.launch.mesh import make_test_mesh
+from repro.scale.stacked import evolve_counts_for, stacked_evolve_exact
+from repro.sharding.rules import tree_stacked_shardings
+from repro.utils.tree import tree_index
+
+rng = np.random.default_rng(11)
+shapes = {"conv/w": (8, 3, 3, 8, 16), "fc": {"w": (8, 64, 10), "b": (8, 10)}}
+# weights and grads on a coarse grid, so that ties straddle the cuts
+grid = lambda s: jnp.asarray(np.round(rng.normal(size=s) * 2) / 2, jnp.float32)
+m = jax.tree.map(lambda s: jnp.asarray(rng.random(s) < 0.5, jnp.float32),
+                 shapes, is_leaf=lambda x: isinstance(x, tuple))
+m["fc"]["b"] = jnp.ones_like(m["fc"]["b"])
+w = jax.tree.map(lambda s, mm: grid(s) * mm, shapes, m,
+                 is_leaf=lambda x: isinstance(x, tuple))
+g = jax.tree.map(lambda s: grid(s), shapes,
+                 is_leaf=lambda x: isinstance(x, tuple))
+counts = evolve_counts_for(layer_nnz_budgets(
+    tree_index(w, 0), erk_densities_for_params(tree_index(w, 0), 0.5)), 0.3)
+single = jax.jit(stacked_evolve_exact)(w, m, g, counts)
+sh = tree_stacked_shardings(w, make_test_mesh(data=4, model=1))
+args = [jax.device_put(t, sh) for t in (w, m, g)]
+step = jax.jit(stacked_evolve_exact, out_shardings=(sh, sh))
+meshed = step(*args, counts)
+hlo = step.lower(*args, counts).compile().as_text()
+assert "all-gather" not in hlo, "a key block is gathered"
+for a, b in zip(jax.tree.leaves(meshed), jax.tree.leaves(single)):
+    assert len(a.sharding.device_set) == 4
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+print("ok")
+"""
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert r.stdout.strip().splitlines()[-1] == "ok"
 
 
 # ---------------------------------------------------------------------------
