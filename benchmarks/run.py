@@ -16,7 +16,6 @@ from benchmarks.common import emit
 
 MODULES = [
     "comm_flops",        # paper-exact Table 1/2 comm + FLOPs columns
-    "kernels_bench",     # Pallas kernel micro-benchmarks
     "table1_accuracy",   # Table 1 (accuracy, both partitions)
     "table2_topology",   # Table 2/8/9
     "table3_heterogeneous",  # Table 3 + Fig 4

@@ -7,8 +7,9 @@ run on a real machine:
     PYTHONPATH=src python examples/train_e2e.py --d-model 768 --layers 12 \
         --steps 300 --clients 4
 
-This wraps ``repro.launch.train lm`` — the same code path the mesh-scale
-train step uses (gossip_average_stacked + masked SGD + mask evolution).
+This wraps ``repro.launch.train lm``: the stacked gossip of
+``repro.core.gossip.masked_gossip_stacked``, which the mesh-scale train step
+also uses, then masked SGD and mask evolution.
 """
 import argparse
 import subprocess

@@ -7,21 +7,19 @@ masks m_j, client k forms
 
 i.e. each coordinate is averaged over the subset of peers that actually hold
 it.  Non-sparsifiable leaves (all-ones masks) reduce to the plain gossip
-average.  Two implementations:
+average.  Two forms:
 
-* ``gossip_average_stacked`` — all clients at once via adjacency einsum over a
-  stacked client axis.  This is the form lowered onto the TPU mesh (the
-  client axis is sharded over 'data'/'pod'; GSPMD emits the collectives) and
-  is also what the CPU simulator uses.
 * ``gossip_average_one`` — single-client form (list of neighbor trees), used
-  by the per-client simulator paths and tests.
-
-The fused elementwise core (num/den ⊙ m) has a Pallas TPU kernel in
-``repro.kernels.gossip_avg``; the jnp fallback here is the oracle.
+  by the per-client engine and simulator paths, and the tests' oracle.
+* ``masked_gossip_stacked`` — all clients at once over a stacked (K-leading)
+  client dim, the one stacked gossip: ``ScaleEngine``'s round program, the
+  LM step of ``launch/train.py`` and the dry-run steps all call it.  Under a
+  mesh the K dim is sharded over the client axes and GSPMD emits the
+  collectives.  ``plain_mix_stacked`` is D-PSGD's row-stochastic mixing in
+  the same stacked form.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Any
 
 import jax
@@ -29,38 +27,10 @@ import jax.numpy as jnp
 
 PyTree = Any
 
-
 def _intersection_avg(num, den, mask):
     """num/den on held coordinates, zero elsewhere.  den>=1 wherever mask=1."""
     den = jnp.maximum(den, 1.0)
     return (num / den) * mask
-
-
-@partial(jax.jit, static_argnames=())
-def gossip_average_stacked(
-    stacked_params: PyTree,
-    stacked_masks: PyTree,
-    adjacency: jax.Array,
-) -> PyTree:
-    """All-client intersection-weighted gossip.
-
-    Args:
-      stacked_params: pytree with leading client dim K on every leaf.
-      stacked_masks:  same structure, {0,1} masks (all-ones where dense).
-      adjacency: (K, K), A[k, j] = 1 iff k receives j (diag must be 1).
-
-    Returns:
-      stacked w_{·,t+1/2}, same structure/shapes.
-
-    Delegates to ``repro.scale.stacked.masked_gossip_stacked`` — the single
-    stacked gossip implementation (lazy import so ``core`` stays loadable
-    on its own); this fp32-accumulating einsum form is bit-identical to
-    the previous inline body for fp32 trees.
-    """
-    from repro.scale.stacked import masked_gossip_stacked
-
-    return masked_gossip_stacked(stacked_params, stacked_masks, adjacency,
-                                 reduction="einsum")
 
 
 def gossip_average_one(
@@ -86,10 +56,92 @@ def gossip_average_one(
     )
 
 
-@partial(jax.jit, static_argnames=())
-def plain_gossip_stacked(stacked_params: PyTree, mixing: jax.Array) -> PyTree:
-    """D-PSGD style gossip: w_k <- sum_j W[k,j] w_j with row-stochastic W.
-    Delegates to the single stacked implementation in ``repro.scale``."""
-    from repro.scale.stacked import plain_mix_stacked
+REDUCTIONS = ("einsum", "ordered")
 
-    return plain_mix_stacked(stacked_params, mixing, reduction="einsum")
+
+def _check_reduction(reduction: str) -> None:
+    if reduction not in REDUCTIONS:
+        raise ValueError(
+            f"reduction must be one of {REDUCTIONS}, got {reduction!r}")
+
+
+def masked_gossip_stacked(params: PyTree, masks: PyTree, adjacency: jax.Array,
+                          reduction: str = "einsum",
+                          accum_dtype=jnp.float32) -> PyTree:
+    """Intersection-weighted gossip over the stacked client dim.
+
+    ``adjacency`` is the (K, K) receive matrix with unit diagonal (client k
+    mixes the models of every j with A[k, j] > 0, itself included).
+
+    * ``"einsum"``: num/den are adjacency matmuls over K — the SPMD form
+      (GSPMD turns the K-sharded contraction into collectives).  XLA picks
+      the fp reduction order, so results match the reference engine to a
+      few ulps, not bitwise.
+    * ``"ordered"``: a fori-loop fold that adds contributions in exactly the
+      reference order (own model first, then senders in ascending index),
+      bit-identical to ``gossip_average_one`` per client.
+    """
+    _check_reduction(reduction)
+    a = adjacency.astype(accum_dtype)
+
+    if reduction == "einsum":
+
+        def one(w, m):
+            mf = m.astype(accum_dtype)
+            wf = w.astype(accum_dtype) * mf
+            num = jnp.einsum("kj,j...->k...", a, wf)
+            den = jnp.einsum("kj,j...->k...", a, mf)
+            mix = (num.astype(jnp.float32)
+                   / jnp.maximum(den.astype(jnp.float32), 1.0))
+            return (mix * m.astype(jnp.float32)).astype(w.dtype)
+
+        return jax.tree.map(one, params, masks)
+
+    k_clients = adjacency.shape[0]
+    # off-diagonal gate: sender j contributes to receiver k iff an edge
+    gate = a * (1.0 - jnp.eye(k_clients, dtype=accum_dtype))
+    gate = (gate > 0).astype(accum_dtype)
+
+    def one(w, m):
+        mf = m.astype(accum_dtype)
+        wf = w.astype(accum_dtype)
+        bshape = (k_clients,) + (1,) * (w.ndim - 1)
+
+        def body(j, carry):
+            num, den = carry
+            g = gate[:, j].reshape(bshape)
+            return (num + g * (wf[j] * mf[j]), den + g * mf[j])
+
+        num, den = jax.lax.fori_loop(0, k_clients, body, (wf * mf, mf))
+        mix = (num.astype(jnp.float32)
+               / jnp.maximum(den.astype(jnp.float32), 1.0))
+        return (mix * m.astype(jnp.float32)).astype(w.dtype)
+
+    return jax.tree.map(one, params, masks)
+
+
+def plain_mix_stacked(params: PyTree, mixing: jax.Array,
+                      reduction: str = "einsum") -> PyTree:
+    """Row-stochastic mixing ``w_k <- sum_j W[k, j] w_j`` over the K dim
+    (D-PSGD / Metropolis).  ``"ordered"`` adds terms in ascending sender
+    index, matching the reference engine's accumulation bit for bit."""
+    _check_reduction(reduction)
+    if reduction == "einsum":
+
+        def one(w):
+            return jnp.einsum("kj,j...->k...", mixing.astype(w.dtype), w)
+
+        return jax.tree.map(one, params)
+
+    k_clients = mixing.shape[0]
+
+    def one(w):
+        wm = mixing.astype(w.dtype)
+        bshape = (k_clients,) + (1,) * (w.ndim - 1)
+
+        def body(j, acc):
+            return acc + wm[:, j].reshape(bshape) * w[j]
+
+        return jax.lax.fori_loop(0, k_clients, body, jnp.zeros_like(w))
+
+    return jax.tree.map(one, params)

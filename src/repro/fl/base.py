@@ -7,6 +7,9 @@ per-layer analytic FLOPs map used by the accounting.
 ``local_sgd`` runs the paper's local phase: E epochs of minibatch SGD with
 fixed batch size (epochs are padded to whole batches so a single jitted step
 serves all clients), optional DisPFL-style gradient masking.
+``stacked_local_phase`` is the same phase for every client at once over a
+padded ``stacked_schedule``: RoundEngine's vmap path and ScaleEngine's round
+program both run it.
 
 Determinism: callers must pass a *per-client, per-round* generator (see
 ``repro.fl.engine.derive_rng``) — never one generator shared across clients,
@@ -156,6 +159,26 @@ def _pad_order(n: int, bs: int, rng: np.random.Generator) -> np.ndarray:
     return order
 
 
+def stacked_schedule(clients, rngs, epochs: int,
+                     bs: int) -> tuple[np.ndarray, np.ndarray]:
+    """The stacked local phase's padded batch schedule for ``clients``.
+
+    Client i draws ``epochs`` permutations from ``rngs[i]`` exactly as
+    ``local_sgd`` does; ragged schedules are padded to the longest with
+    recycled batches.  Returns int32 row indices ``(A, S, bs)`` into each
+    client's training set and live flags ``(A, S)``: a padded step is not
+    live, and ``stacked_local_phase`` makes it a no-op.
+    """
+    orders = [np.concatenate([_pad_order(c.n_train, bs, rng)
+                              for _ in range(epochs)])
+              for c, rng in zip(clients, rngs)]
+    s_max = max(len(o) // bs for o in orders)
+    idx = np.stack([np.resize(o, s_max * bs) for o in orders]).astype(
+        np.int32)
+    live = np.arange(s_max) < np.array([[len(o) // bs] for o in orders])
+    return idx.reshape(-1, s_max, bs), live
+
+
 def local_sgd(
     task: Task,
     params: PyTree,
@@ -181,6 +204,51 @@ def local_sgd(
             else:
                 params, state = sgd_step(params, grads, state, opt, lr)
     return params
+
+
+def stacked_local_phase(apply_fn: Callable, opt: SGDConfig, params: PyTree,
+                        masks: Optional[PyTree], bx: jax.Array, by: jax.Array,
+                        live: jax.Array, lr: jax.Array) -> PyTree:
+    """``local_sgd`` for every client at once, as a traceable function.
+
+    A vmap over the stacked client dim of a lax.scan over the padded step
+    schedule (``stacked_schedule``): masked/unmasked SGD steps from
+    ``repro.optim``, padded (non-live) steps are exact no-ops, momentum is
+    zero-initialized stacked per-client state.  ``masks`` is None for
+    unmasked strategies.  RoundEngine's vmap path jits it alone;
+    ScaleEngine traces it into its round program.
+    """
+
+    def loss(p, x, y):
+        return softmax_xent(apply_fn(p, x), y)
+
+    grad = jax.grad(loss)
+    use_mask = masks is not None
+
+    def per_client(p, m, cx, cy, lv):
+        def body(carry, xyl):
+            w, st = carry
+            x, y, alive = xyl
+            g = grad(w, x, y)
+            if use_mask:
+                w2, st2 = masked_sgd_step(w, g, m, st, opt, lr)
+            else:
+                w2, st2 = sgd_step(w, g, st, opt, lr)
+            # jnp.where keeps a live step bit-identical to the plain step
+            w = jax.tree.map(lambda o, nn: jnp.where(alive, nn, o), w, w2)
+            st = jax.tree.map(lambda o, nn: jnp.where(alive, nn, o), st, st2)
+            return (w, st), None
+
+        st0 = ({"mu": jax.tree.map(jnp.zeros_like, p)}
+               if opt.momentum != 0.0 else {})
+        (p, _), _ = jax.lax.scan(body, (p, st0), (cx, cy, lv))
+        return p
+
+    if use_mask:
+        return jax.vmap(per_client)(params, masks, bx, by, live)
+    return jax.vmap(
+        lambda p, cx, cy, lv: per_client(p, None, cx, cy, lv))(
+            params, bx, by, live)
 
 
 def finetune_clients(

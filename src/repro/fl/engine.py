@@ -29,17 +29,19 @@ loop — so results are independent of client iteration order and a resumed
 run is bit-identical to an uninterrupted one.
 
 Fast path: for homogeneous-density clients, the local phase is executed as
-one jitted ``jax.vmap``-over-clients ``lax.scan`` instead of a Python loop
-over K clients (``local_exec="vmap"`` or ``"auto"``); batch orders are
-drawn from the same per-client generators, ragged step counts are padded
-and masked, and momentum travels as stacked per-client optimizer state —
-so the schedule and update rule match the per-client loop exactly.
+one jitted ``fl.base.stacked_local_phase`` (a ``jax.vmap``-over-clients
+``lax.scan``) instead of a Python loop over K clients (``local_exec="vmap"``
+or ``"auto"``); ``fl.base.stacked_schedule`` draws the batch orders from the
+same per-client generators and pads ragged step counts, which the phase
+masks, and momentum travels as stacked per-client optimizer state — so the
+schedule and update rule match the per-client loop exactly.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import time
+from functools import partial
 from typing import Any, Callable, Iterator, Optional, Sequence
 
 import jax
@@ -53,14 +55,13 @@ from repro.fl.base import (
     FLConfig,
     FLResult,
     Task,
-    _pad_order,
     evaluate_clients,
-    local_sgd,
     rounds_to_targets,
+    stacked_local_phase,
+    stacked_schedule,
 )
-from repro.models.common import softmax_xent
 from repro.obs import CounterSet, SeriesSet, span
-from repro.optim import SGDConfig, masked_sgd_step, sgd_step
+from repro.optim import SGDConfig
 from repro.sparse import pack_tree, unpack_mask_tree, unpack_tree
 from repro.utils.tree import tree_index, tree_nnz, tree_size, tree_stack
 
@@ -477,7 +478,7 @@ class RoundEngine:
         self._flops: dict[str, list[float]] = {
             "per_round_flops": [], "dense_per_round_flops": [],
             "fwd_flops_per_sample": []}
-        self._vmap_fns: dict[bool, Callable] = {}
+        self._local_fn: Optional[Callable] = None
         self.obs = CounterSet("fl.engine")
         self.obs.gauge("rounds_completed", fn=lambda: self._next_round)
         self.obs.gauge("cum_flops", fn=lambda: float(
@@ -699,89 +700,30 @@ class RoundEngine:
         # the max step count and masks the padded updates (no-op steps)
         return True, ""
 
-    def _vmapped_fn(self, use_mask: bool) -> Callable:
-        if use_mask in self._vmap_fns:
-            return self._vmap_fns[use_mask]
-        task = self.task
-        # same update rule as the per-client loop (repro.optim); momentum
-        # rides along as stacked per-client optimizer state, zero-initialized
-        # each local phase exactly like the loop's init_sgd
-        opt = SGDConfig(momentum=self.cfg.momentum,
-                        weight_decay=self.cfg.weight_decay)
-
-        def loss(p, x, y):
-            return softmax_xent(task.apply_fn(p, x), y)
-
-        grad = jax.grad(loss)
-
-        def per_client(p, m, bx, by, live, lr):
-            def body(carry, xyl):
-                w, st = carry
-                x, y, lv = xyl
-                g = grad(w, x, y)
-                if use_mask:
-                    w2, st2 = masked_sgd_step(w, g, m, st, opt, lr)
-                else:
-                    w2, st2 = sgd_step(w, g, st, opt, lr)
-                # padded steps (ragged per-client schedules) are no-ops;
-                # jnp.where keeps live steps bit-identical to the plain step
-                w = jax.tree.map(lambda o, n: jnp.where(lv, n, o), w, w2)
-                st = jax.tree.map(lambda o, n: jnp.where(lv, n, o), st, st2)
-                return (w, st), None
-
-            st0 = ({"mu": jax.tree.map(jnp.zeros_like, p)}
-                   if opt.momentum != 0.0 else {})
-            (p, _), _ = jax.lax.scan(body, (p, st0), (bx, by, live))
-            return p
-
-        if use_mask:
-            fn = jax.jit(jax.vmap(per_client, in_axes=(0, 0, 0, 0, 0, None)))
-        else:
-            fn = jax.jit(jax.vmap(
-                lambda p, bx, by, live, lr: per_client(p, None, bx, by, live, lr),
-                in_axes=(0, 0, 0, 0, None)))
-        self._vmap_fns[use_mask] = fn
-        return fn
-
     def _vmap_local_phase(self, ctx: RoundCtx, active: list[int]) -> None:
         strat = self.strategy
         state = self.state
         epochs = strat.local_epochs(state, ctx)
-        bs = min(self.cfg.batch_size,
-                 min(self.clients[k].n_train for k in active))
-        orders = []
-        for k in active:
-            # identical draws to the per-client loop: one permutation per
-            # epoch from the client's (seed, round, k) generator
-            rng = ctx.client_rng(k)
-            orders.append(np.concatenate(
-                [_pad_order(self.clients[k].n_train, bs, rng)
-                 for _ in range(epochs)]))
-        # ragged schedules: pad every client to the max step count with
-        # recycled batches, masked out in the scan (live=False -> no-op step)
-        s_max = max(len(o) // bs for o in orders)
-        xb, yb, live = [], [], []
-        for k, order in zip(active, orders):
-            steps = len(order) // bs
-            c = self.clients[k]
-            padded = np.resize(order, s_max * bs)
-            xb.append(c.train_x[padded].reshape(
-                (s_max, bs) + c.train_x.shape[1:]))
-            yb.append(c.train_y[padded].reshape(s_max, bs))
-            live.append(np.arange(s_max) < steps)
-        live = jnp.asarray(np.stack(live))
+        clients = [self.clients[k] for k in active]
+        bs = min(self.cfg.batch_size, min(c.n_train for c in clients))
+        # identical draws to the per-client loop: one permutation per epoch
+        # from the client's (seed, round, k) generator
+        idx, live = stacked_schedule(
+            clients, [ctx.client_rng(k) for k in active], epochs, bs)
+        bx = np.stack([c.train_x[i] for c, i in zip(clients, idx)])
+        by = np.stack([c.train_y[i] for c, i in zip(clients, idx)])
         stacked = tree_stack([strat.local_params(state, k) for k in active])
         masks = [strat.local_mask(state, k) for k in active]
-        use_mask = masks[0] is not None
-        lr = jnp.float32(ctx.lr)
-        if use_mask:
-            new = self._vmapped_fn(True)(
-                stacked, tree_stack(masks),
-                jnp.asarray(np.stack(xb)), jnp.asarray(np.stack(yb)), live, lr)
-        else:
-            new = self._vmapped_fn(False)(
-                stacked, jnp.asarray(np.stack(xb)), jnp.asarray(np.stack(yb)),
-                live, lr)
+        masks = None if masks[0] is None else tree_stack(masks)
+        if self._local_fn is None:
+            # same update rule as the per-client loop (repro.optim)
+            opt = SGDConfig(momentum=self.cfg.momentum,
+                            weight_decay=self.cfg.weight_decay)
+            self._local_fn = jax.jit(
+                partial(stacked_local_phase, self.task.apply_fn, opt))
+        new = self._local_fn(stacked, masks, jnp.asarray(bx),
+                             jnp.asarray(by), jnp.asarray(live),
+                             jnp.float32(ctx.lr))
         for i, k in enumerate(active):
             strat.set_local(state, k, tree_index(new, i))
 

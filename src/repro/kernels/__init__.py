@@ -1,5 +1,7 @@
-"""Pallas kernels for the paper's sparse hot spots, their jnp oracles
-(``ref.py``) and jitted wrappers (``ops.py``).
+"""Pallas kernels, their jnp oracles (``ref.py``) and wrappers (``ops.py``):
+the block-sparse masked matmul of the serving launch (``masked_matmul.py``)
+and the packed-payload accumulate (``packed_accum.py``), which the TPU
+compiler refuses and no default path runs.
 
 Every ``pallas_call`` takes its mode from ``_interpret()``: compiled
 (Mosaic) on a TPU, the Pallas interpreter on the CPU so tests run the

@@ -1,9 +1,8 @@
 """Pallas TPU kernel: block-sparse masked matmul  y = x @ (w ⊙ m).
 
-This is the TPU-native realization of DisPFL's sparse-compute saving
-(DESIGN.md §3): the MXU has no unstructured-sparsity path, so the
-coordinate mask is summarized into a (K/bk, N/bn) *block mask*; tiles whose
-block is empty are skipped entirely via ``@pl.when`` on a scalar-prefetched
+This is the TPU-native realization of DisPFL's sparse-compute saving: the
+MXU has no unstructured-sparsity path, so the coordinate mask is
+summarized into a (K/bk, N/bn) *block mask*; tiles whose block is empty are skipped entirely via ``@pl.when`` on a scalar-prefetched
 SMEM mask — the MXU never sees them.  Non-empty tiles multiply the
 elementwise-masked weights, so the result equals the dense reference
 exactly (``ref.masked_matmul_ref``).

@@ -5,14 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def gossip_avg_ref(w_stack: jax.Array, m_stack: jax.Array,
-                   own_mask: jax.Array) -> jax.Array:
-    """w_stack, m_stack: (J, N); own_mask: (N,)."""
-    num = jnp.sum(w_stack.astype(jnp.float32), axis=0)
-    den = jnp.maximum(jnp.sum(m_stack.astype(jnp.float32), axis=0), 1.0)
-    return ((num / den) * own_mask.astype(jnp.float32)).astype(w_stack.dtype)
-
-
 def masked_matmul_ref(x: jax.Array, w: jax.Array, mask: jax.Array) -> jax.Array:
     return (x @ (w * mask.astype(w.dtype))).astype(x.dtype)
 
@@ -34,14 +26,3 @@ def packed_accum_ref(num: jax.Array, den: jax.Array, flags: jax.Array,
     contrib = jnp.where(flags, vals, 0.0)
     return (num + jnp.float32(alpha) * contrib,
             den + flags.astype(jnp.float32))
-
-
-def prune_regrow_ref(w: jax.Array, g: jax.Array, m: jax.Array,
-                     w_thresh, g_thresh):
-    wf = w.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    keep = (m > 0) & (jnp.abs(wf) >= w_thresh)
-    grown = (m <= 0) & (jnp.abs(gf) >= g_thresh) & (jnp.abs(gf) > 0)
-    new_m = (keep | grown).astype(m.dtype)
-    new_w = (wf * keep.astype(jnp.float32)).astype(w.dtype)
-    return new_m, new_w

@@ -124,13 +124,12 @@ def make_train_step(api: ModelAPI, plan: ScalePlan, gossip: str = "einsum"):
     """One DisPFL round step: intersection gossip + one masked-SGD step.
 
     gossip: 'einsum' (adjacency matmul over the stacked client dim — the
-    baseline, delegating to ``repro.scale.masked_gossip_stacked``, the one
-    stacked gossip implementation shared with ``ScaleEngine``), 'none'
-    (ablation / non-FL training), or 'ppermute' (neighbor exchange via
-    shard_map collective_permute — §Perf optimized path, see
-    launch/gossip_opt.py).
+    baseline, ``repro.core.gossip.masked_gossip_stacked``, the one stacked
+    gossip, shared with ``ScaleEngine``), 'none' (ablation / non-FL
+    training), or 'ppermute' (neighbor exchange via shard_map
+    collective_permute, see launch/gossip_opt.py).
     """
-    from repro.scale.stacked import masked_gossip_stacked
+    from repro.core.gossip import masked_gossip_stacked
 
     wd = WEIGHT_DECAY
 
@@ -172,12 +171,10 @@ def make_mask_update_step(api: ModelAPI, plan: ScalePlan, density: float = 0.5):
 
     Per client: dense gradient on one batch, then the threshold-based
     stacked prune/regrow of ``repro.scale.stacked_prune_regrow_threshold``
-    (kth order statistics via sort — identical semantics to
-    kernels/ops.prune_regrow, up to ties).  Layer budgets are static
-    (``density`` x numel), so the program is shape-static and lowers like
-    the train step.  Practical for <=30B-param archs (the sort is
-    O(n log n) per leaf); jamba-scale masks would use a sampled-quantile
-    threshold instead (documented in DESIGN.md).
+    (kth order statistics via sort — ``core.evolve.evolve_mask_layer``'s
+    selection, up to ties).  Layer budgets are static (``density`` x
+    numel), so the program is shape-static and lowers like the train step.
+    Practical for <=30B-param archs (the sort is O(n log n) per leaf).
     """
     from repro.scale.stacked import stacked_prune_regrow_threshold
 

@@ -224,7 +224,7 @@ def run_lm(args) -> dict:
 
     from repro.configs import SMOKE_ARCHS, get_arch
     from repro.core.evolve import cosine_prune_rate, evolve_masks, layer_nnz_budgets
-    from repro.core.gossip import gossip_average_stacked
+    from repro.core.gossip import masked_gossip_stacked
     from repro.core.masks import apply_mask, erk_densities_for_params, init_mask
     from repro.core.topology import make_adjacency
     from repro.data import make_lm_corpus
@@ -262,7 +262,8 @@ def run_lm(args) -> dict:
 
     @jax.jit
     def step(stacked_params, stacked_masks, batch, adjacency, lr):
-        mixed = gossip_average_stacked(stacked_params, stacked_masks, adjacency)
+        mixed = masked_gossip_stacked(stacked_params, stacked_masks,
+                                      adjacency, reduction="einsum")
 
         def total(ps):
             losses, _ = jax.vmap(lambda p, b: api.train_loss(p, b))(ps, batch)

@@ -53,13 +53,10 @@ from repro.scale.engine import ScaleEngine  # noqa: F401
 from repro.scale.stacked import (  # noqa: F401
     StackedPacked,
     fold_stacked,
-    masked_gossip_stacked,
     pack_stacked,
-    plain_mix_stacked,
     split_stacked,
     stack_payloads,
     stacked_evolve_exact,
-    stacked_local_phase,
     stacked_nnz_per_client,
     stacked_prune_regrow_threshold,
     unpack_stacked,

@@ -46,10 +46,11 @@ import numpy as np
 from repro.fl.base import (
     FLResult,
     Task,
-    _pad_order,
     evaluate_clients_stacked,
     rounds_to_targets,
     stack_eval_arrays,
+    stacked_local_phase,
+    stacked_schedule,
 )
 from repro.fl.engine import Callback, RoundCtx, RoundEngine, RoundMetrics, StrategyBase
 from repro.core.accounting import CommReport, FlopsReport
@@ -62,12 +63,7 @@ from repro.obs import (
     span,
 )
 from repro.optim import SGDConfig
-from repro.scale.stacked import (
-    evolve_topk_work,
-    pack_stacked,
-    split_stacked,
-    stacked_local_phase,
-)
+from repro.scale.stacked import evolve_topk_work, pack_stacked, split_stacked
 from repro.scale.strategy import make_stacked
 
 PyTree = Any
@@ -258,27 +254,16 @@ class ScaleEngine(RoundEngine):
         return self._images
 
     def _batch_schedule(self, ctx: RoundCtx):
-        """Stacked padded batch schedule — the same permutations, padding
-        and live-masking as ``RoundEngine._vmap_local_phase`` (and therefore
-        the same draws as the per-client reference loop): each step's rows
-        of ``_device_images``, (K, steps, bs), its labels and its live
-        flags."""
-        cfg = self.cfg
-        epochs = self.strategy.local_epochs({}, ctx)
-        bs = min(cfg.batch_size, min(c.n_train for c in self.clients))
-        orders = []
-        for k in range(len(self.clients)):
-            rng = ctx.client_rng(k)
-            orders.append(np.concatenate(
-                [_pad_order(self.clients[k].n_train, bs, rng)
-                 for _ in range(epochs)]))
-        s_max = max(len(o) // bs for o in orders)
-        idx = np.stack([np.resize(o, s_max * bs) for o in orders]).astype(
-            np.int32)
-        labels = np.stack([c.train_y[i] for c, i in zip(self.clients, idx)])
-        live = np.arange(s_max) < np.array([[len(o) // bs] for o in orders])
-        return (idx.reshape(-1, s_max, bs), labels.reshape(-1, s_max, bs),
-                live)
+        """``stacked_schedule``'s padded batch schedule — the same draws as
+        the reference engine — as each step's rows of ``_device_images``,
+        (K, steps, bs), its labels and its live flags."""
+        clients = self.clients
+        bs = min(self.cfg.batch_size, min(c.n_train for c in clients))
+        idx, live = stacked_schedule(
+            clients, [ctx.client_rng(k) for k in range(len(clients))],
+            self.strategy.local_epochs({}, ctx), bs)
+        labels = np.stack([c.train_y[i] for c, i in zip(clients, idx)])
+        return idx, labels, live
 
     def _evolve_batches(self, ctx: RoundCtx):
         """The mask-search batches' rows of ``_device_images`` and their

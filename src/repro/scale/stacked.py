@@ -8,22 +8,6 @@ the client axes and GSPMD emits the collectives for the gossip fold.
 
 Primitives
 ----------
-``masked_gossip_stacked``   DisPFL's intersection-weighted gossip as an
-                            adjacency-weighted masked fold over the K dim.
-                            ``reduction="einsum"`` is the fast SPMD form
-                            (one matmul per leaf; fp reduction order is
-                            XLA's); ``reduction="ordered"`` reproduces the
-                            reference engine's per-client accumulation
-                            order (own model first, then neighbors in
-                            ascending index) bit for bit — the form the
-                            golden-equivalence suite pins down.
-``plain_mix_stacked``       row-stochastic mixing (D-PSGD Metropolis), same
-                            two reductions.
-``stacked_local_phase``     the engine's vmap-over-clients local SGD scan
-                            (identical update rule, ragged schedules padded
-                            and live-masked, momentum as stacked state) as
-                            a *traceable* function, so it can fuse into the
-                            single round program.
 ``stacked_evolve_exact``    Alg. 2 prune/regrow batched over clients with
                             *traced* per-layer (n_keep, n_prune) counts, and
                             no recompilation when the cosine schedule or an
@@ -42,6 +26,11 @@ Primitives
                             ``launch/steps.make_mask_update_step``; it now
                             lives here so there is exactly one stacked
                             mask-search implementation.
+
+The round's other two phases live below this package, where both engines
+reach them: the stacked gossip in ``repro.core.gossip``
+(``masked_gossip_stacked``, ``plain_mix_stacked``) and the local SGD phase
+in ``repro.fl.base`` (``stacked_local_phase``).
 
 Stacked packed payloads
 -----------------------
@@ -63,9 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.common import softmax_xent
 from repro.obs import Counter
-from repro.optim import SGDConfig, masked_sgd_step, sgd_step
 from repro.sparse.packed import (
     PackedSparse,
     _is_packed,
@@ -76,149 +63,6 @@ from repro.sparse.packed import (
 from repro.utils.tree import tree_leaves_with_path, tree_map_with_path
 
 PyTree = Any
-
-REDUCTIONS = ("einsum", "ordered")
-
-
-def _check_reduction(reduction: str) -> None:
-    if reduction not in REDUCTIONS:
-        raise ValueError(
-            f"reduction must be one of {REDUCTIONS}, got {reduction!r}")
-
-
-# ---------------------------------------------------------------------------
-# Stacked gossip folds
-# ---------------------------------------------------------------------------
-
-
-def masked_gossip_stacked(params: PyTree, masks: PyTree, adjacency: jax.Array,
-                          reduction: str = "einsum",
-                          accum_dtype=jnp.float32) -> PyTree:
-    """Intersection-weighted gossip over the stacked client dim.
-
-    ``adjacency`` is the (K, K) receive matrix with unit diagonal (client k
-    mixes the models of every j with A[k, j] > 0, itself included).
-
-    * ``"einsum"``: num/den are adjacency matmuls over K — the SPMD form
-      (GSPMD turns the K-sharded contraction into collectives).  XLA picks
-      the fp reduction order, so results match the reference engine to a
-      few ulps, not bitwise.
-    * ``"ordered"``: a fori-loop fold that adds contributions in exactly the
-      reference order (own model first, then senders in ascending index),
-      bit-identical to ``core.gossip.gossip_average_one`` per client.
-    """
-    _check_reduction(reduction)
-    a = adjacency.astype(accum_dtype)
-
-    if reduction == "einsum":
-
-        def one(w, m):
-            mf = m.astype(accum_dtype)
-            wf = w.astype(accum_dtype) * mf
-            num = jnp.einsum("kj,j...->k...", a, wf)
-            den = jnp.einsum("kj,j...->k...", a, mf)
-            mix = (num.astype(jnp.float32)
-                   / jnp.maximum(den.astype(jnp.float32), 1.0))
-            return (mix * m.astype(jnp.float32)).astype(w.dtype)
-
-        return jax.tree.map(one, params, masks)
-
-    k_clients = adjacency.shape[0]
-    # off-diagonal gate: sender j contributes to receiver k iff an edge
-    gate = a * (1.0 - jnp.eye(k_clients, dtype=accum_dtype))
-    gate = (gate > 0).astype(accum_dtype)
-
-    def one(w, m):
-        mf = m.astype(accum_dtype)
-        wf = w.astype(accum_dtype)
-        bshape = (k_clients,) + (1,) * (w.ndim - 1)
-
-        def body(j, carry):
-            num, den = carry
-            g = gate[:, j].reshape(bshape)
-            return (num + g * (wf[j] * mf[j]), den + g * mf[j])
-
-        num, den = jax.lax.fori_loop(0, k_clients, body, (wf * mf, mf))
-        mix = (num.astype(jnp.float32)
-               / jnp.maximum(den.astype(jnp.float32), 1.0))
-        return (mix * m.astype(jnp.float32)).astype(w.dtype)
-
-    return jax.tree.map(one, params, masks)
-
-
-def plain_mix_stacked(params: PyTree, mixing: jax.Array,
-                      reduction: str = "einsum") -> PyTree:
-    """Row-stochastic mixing ``w_k <- sum_j W[k, j] w_j`` over the K dim
-    (D-PSGD / Metropolis).  ``"ordered"`` adds terms in ascending sender
-    index, matching the reference engine's accumulation bit for bit."""
-    _check_reduction(reduction)
-    if reduction == "einsum":
-
-        def one(w):
-            return jnp.einsum("kj,j...->k...", mixing.astype(w.dtype), w)
-
-        return jax.tree.map(one, params)
-
-    k_clients = mixing.shape[0]
-
-    def one(w):
-        wm = mixing.astype(w.dtype)
-        bshape = (k_clients,) + (1,) * (w.ndim - 1)
-
-        def body(j, acc):
-            return acc + wm[:, j].reshape(bshape) * w[j]
-
-        return jax.lax.fori_loop(0, k_clients, body, jnp.zeros_like(w))
-
-    return jax.tree.map(one, params)
-
-
-# ---------------------------------------------------------------------------
-# Stacked local phase (traceable; fuses into the single round program)
-# ---------------------------------------------------------------------------
-
-
-def stacked_local_phase(apply_fn: Callable, opt: SGDConfig, params: PyTree,
-                        masks: Optional[PyTree], bx: jax.Array, by: jax.Array,
-                        live: jax.Array, lr: jax.Array) -> PyTree:
-    """The engine's vmap local phase as a plain traceable function.
-
-    Identical semantics to ``RoundEngine._vmapped_fn``: a lax.scan over the
-    padded step schedule per client, masked/unmasked SGD steps from
-    ``repro.optim``, padded (non-live) steps are exact no-ops, momentum is
-    zero-initialized stacked per-client state.
-    """
-
-    def loss(p, x, y):
-        return softmax_xent(apply_fn(p, x), y)
-
-    grad = jax.grad(loss)
-    use_mask = masks is not None
-
-    def per_client(p, m, cx, cy, lv):
-        def body(carry, xyl):
-            w, st = carry
-            x, y, alive = xyl
-            g = grad(w, x, y)
-            if use_mask:
-                w2, st2 = masked_sgd_step(w, g, m, st, opt, lr)
-            else:
-                w2, st2 = sgd_step(w, g, st, opt, lr)
-            w = jax.tree.map(lambda o, nn: jnp.where(alive, nn, o), w, w2)
-            st = jax.tree.map(lambda o, nn: jnp.where(alive, nn, o), st, st2)
-            return (w, st), None
-
-        st0 = ({"mu": jax.tree.map(jnp.zeros_like, p)}
-               if opt.momentum != 0.0 else {})
-        (p, _), _ = jax.lax.scan(body, (p, st0), (cx, cy, lv))
-        return p
-
-    if use_mask:
-        return jax.vmap(per_client)(params, masks, bx, by, live)
-    return jax.vmap(
-        lambda p, cx, cy, lv: per_client(p, None, cx, cy, lv))(
-            params, bx, by, live)
-
 
 # ---------------------------------------------------------------------------
 # Stacked mask evolution — exact (golden) and threshold (giant-arch) forms
@@ -397,8 +241,8 @@ def stacked_prune_regrow_threshold(
 ) -> tuple[PyTree, PyTree]:
     """Threshold-based stacked prune/regrow for giant archs.
 
-    Per client and leaf: kth-order-statistic thresholds via sort (identical
-    semantics to ``kernels/ops.prune_regrow`` up to ties).  Layer budgets
+    Per client and leaf: kth-order-statistic thresholds via sort (the
+    selection of ``core.evolve.evolve_mask_layer`` up to ties).  Layer budgets
     are static (``density`` x numel) so the program is shape-static; the
     |g| > 0 guard keeps zero-gradient coordinates (embedding rows absent
     from the batch) from mass-regrowing on threshold ties at 0.  This is

@@ -32,12 +32,11 @@ import jax
 import numpy as np
 
 from repro.core.accounting import decentralized_comm
+from repro.core.gossip import masked_gossip_stacked, plain_mix_stacked
 from repro.fl.engine import RoundCtx, StrategyBase
 from repro.obs import Counter
 from repro.scale.stacked import (
     evolve_counts_for,
-    masked_gossip_stacked,
-    plain_mix_stacked,
     stacked_evolve_exact,
     stacked_nnz_per_client,
 )

@@ -11,8 +11,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.evolve import _exact_topk_mask, evolve_masks, layer_nnz_budgets
-from repro.core.gossip import gossip_average_one
+from repro.core.evolve import (
+    _exact_topk_mask,
+    evolve_mask_layer,
+    evolve_masks,
+    layer_nnz_budgets,
+)
+from repro.core.gossip import plain_mix_stacked
 from repro.core.masks import erk_densities_for_params
 from repro.core.topology import make_adjacency
 from repro.data import build_federated_image_task
@@ -28,9 +33,7 @@ from repro.scale import (
     ScaleEngine,
     fold_stacked,
     make_stacked,
-    masked_gossip_stacked,
     pack_stacked,
-    plain_mix_stacked,
     split_stacked,
     stack_payloads,
     stacked_evolve_exact,
@@ -297,28 +300,6 @@ def _random_world(k=6, density=0.5, seed=0):
     return w, m
 
 
-def test_masked_gossip_stacked_matches_reference_fold():
-    w, m = _random_world()
-    k = 6
-    a = make_adjacency("random", k, 0, 3, 0)
-    ref = []
-    for i in range(k):
-        nbrs = [j for j in range(k) if a[i, j] > 0 and j != i]
-        ref.append(gossip_average_one(
-            tree_index(w, i), tree_index(m, i),
-            [tree_index(w, j) for j in nbrs],
-            [tree_index(m, j) for j in nbrs]))
-    ref = tree_stack(ref)
-    adj = jnp.asarray(a, jnp.float32)
-    ordered = jax.jit(
-        lambda p, q: masked_gossip_stacked(p, q, adj, "ordered"))(w, m)
-    assert _trees_equal(ordered, ref)   # bit-exact accumulation order
-    einsum = jax.jit(
-        lambda p, q: masked_gossip_stacked(p, q, adj, "einsum"))(w, m)
-    for x, y in zip(jax.tree.leaves(einsum), jax.tree.leaves(ref)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
-
-
 def test_plain_mix_stacked_matches_metropolis_reference():
     w, _ = _random_world(seed=3)
     k = 6
@@ -497,6 +478,30 @@ def test_stacked_evolve_exact_matches_core_evolve(grid):
         assert _trees_equal(got_w, tree_stack(ref_w)), rate
     if grid is not None:
         assert all(straddled.values()), straddled
+
+
+@pytest.mark.parametrize("n", [256, 1000, 4096])
+@pytest.mark.parametrize("rate", [0.1, 0.5])
+def test_stacked_evolve_exact_one_leaf_matches_core_evolve(n, rate):
+    """One (K, n) leaf, each client's mask at its budget of n // 2: the
+    batched prune/regrow equals ``evolve_mask_layer`` per client bit for
+    bit, keeps the budget exactly and zeroes every pruned weight."""
+    k, budget = 4, n // 2
+    ks = jax.random.split(jax.random.PRNGKey(n), k + 2)
+    m = jnp.stack([jnp.zeros(n).at[jax.random.permutation(ks[i], n)[:budget]]
+                   .set(1.0) for i in range(k)])
+    w = jax.random.normal(ks[k], (k, n)) * m
+    g = jax.random.normal(ks[k + 1], (k, n))
+    counts = evolve_counts_for({"w": budget}, rate)
+    got_m, got_w = jax.jit(stacked_evolve_exact)(
+        {"w": w}, {"w": m}, {"w": g}, counts)
+    got_m, got_w = np.asarray(got_m["w"]), np.asarray(got_w["w"])
+    for i in range(k):
+        nm, nw = evolve_mask_layer(w[i], m[i], g[i], rate, budget)
+        np.testing.assert_array_equal(got_m[i], np.asarray(nm))
+        np.testing.assert_array_equal(got_w[i], np.asarray(nw))
+    np.testing.assert_array_equal(got_m.sum(axis=1), budget)
+    assert not np.any(got_w[got_m == 0])
 
 
 def test_stacked_evolve_exact_lowers_without_sort_or_scatter():
