@@ -1,4 +1,4 @@
-"""The main path's Pallas kernels compile for a TPU v5e chip, at real widths.
+"""The main path compiles for a TPU v5e chip, at real widths.
 
 Nothing runs: the TPU compiler, which is installed beside JAX, compiles for
 a chip that is described, not attached.  That catches what interpret mode
@@ -12,7 +12,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import repro.kernels.masked_matmul as mm_mod
+import repro.models.cnn as cnn_mod
+from repro.fl.base import stacked_local_phase
 from repro.kernels import ops
+from repro.optim import SGDConfig
+from test_groupnorm import split_groupnorm
 
 pytestmark = pytest.mark.tier1
 
@@ -63,3 +67,29 @@ def test_masked_matmul_compiles_at_resnet18_im2col_shape(one_chip,
     m, k, n = 32 * 4 * 4, 3 * 3 * 512, 512
     hlo = _compile_text(ops.masked_matmul, one_chip, (m, k), (k, n), (k, n))
     assert "tpu_custom_call" in hlo
+
+
+def _local_phase_bytes(one_chip, k=2, steps=1, batch=8, hw=32):
+    """Cost-model bytes of ResNet18-GN's stacked local phase, compiled."""
+    shapes = jax.eval_shape(
+        lambda: cnn_mod.init_resnet18(jax.random.PRNGKey(0), 10))
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda s: spec((k,) + s.shape), shapes)
+    args = (params, params, spec((k, steps, batch, hw, hw, 3)),
+            spec((k, steps, batch), jnp.int32), spec((k, steps), jnp.bool_),
+            spec(()))
+    fn = jax.jit(lambda *a: stacked_local_phase(
+        cnn_mod.resnet18_apply, SGDConfig(), *a))
+    return fn.lower(*args).compile().cost_analysis()["bytes accessed"]
+
+
+def test_groupnorm_keeps_the_local_phase_out_of_layout_copies(one_chip,
+                                                              monkeypatch):
+    # the group split of the activation relayouts it around every norm
+    new = _local_phase_bytes(one_chip)
+    monkeypatch.setattr(cnn_mod, "groupnorm", split_groupnorm)
+    old = _local_phase_bytes(one_chip)
+    assert new <= 0.6 * old, (new, old)
